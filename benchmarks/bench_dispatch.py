@@ -51,10 +51,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import time
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np
@@ -405,6 +402,9 @@ def bench_label_cache(smoke: bool) -> dict:
 
 
 def main():
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes for CI")

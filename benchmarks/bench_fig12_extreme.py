@@ -39,5 +39,7 @@ def run():
 
 if __name__ == "__main__":
     from benchmarks.common import emit
+    from repro.runtime.compile_cache import use_compile_cache
 
+    use_compile_cache()
     emit(run())

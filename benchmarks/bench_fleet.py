@@ -47,10 +47,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import time
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np
@@ -311,6 +308,9 @@ def bench_batched_serve(smoke: bool) -> dict:
 
 
 def main(argv=None):
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes for CI")

@@ -72,8 +72,6 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 
 # Importable both via benchmarks/run.py (repo root on sys.path) and as a
@@ -398,6 +396,9 @@ def bench_scenario_matrix(n_shards, smoke) -> dict:
 def main(argv=None):
     import tempfile
 
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes for CI")

@@ -39,8 +39,6 @@ import json
 import os
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 import numpy as np
 
@@ -126,6 +124,9 @@ def bench_scenario(scen: str, smoke: bool) -> dict:
 
 
 def main(argv=None):
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from repro.data.stream import SCENARIOS
 
     ap = argparse.ArgumentParser()

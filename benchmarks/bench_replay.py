@@ -33,10 +33,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np
@@ -136,6 +133,9 @@ def bench_policy(setup, smoke: bool) -> dict:
 
 
 def main(argv=None):
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="shorter sessions for CI")

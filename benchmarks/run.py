@@ -12,7 +12,9 @@ import traceback
 
 
 def main() -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         bench_dispatch,
         bench_fig3_flops,

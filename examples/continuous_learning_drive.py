@@ -25,14 +25,14 @@ Run:  PYTHONPATH=src python examples/continuous_learning_drive.py [--fast]
           [--dispatch sequential|concurrent] [--online] [--trace PATH]
 """
 import argparse
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 
 
 def main():
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--scenario", default="ES1")

@@ -33,14 +33,14 @@ Run:  PYTHONPATH=src python examples/fleet_drive.py [--fast] [--streams 3]
           [--shards 2] [--fail-at 4] [--parallel 2]
 """
 import argparse
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 
 
 def main():
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--streams", type=int, default=3)

@@ -6,10 +6,6 @@
 
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -67,6 +63,9 @@ def demo_lm():
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("== MX block-floating-point (paper §V-B) ==")
     demo_mx()
     print("== LM architecture zoo (assigned archs) ==")

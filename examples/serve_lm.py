@@ -3,14 +3,13 @@ decode with ring-buffer/sequence KV caches.
 
 Run:  PYTHONPATH=src python examples/serve_lm.py
 """
-import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from repro.launch.serve import main  # noqa: E402
+from repro.launch.serve import main
+from repro.runtime.compile_cache import use_compile_cache
 
 if __name__ == "__main__":
+    use_compile_cache()
     argv = sys.argv[1:] or [
         "--arch", "mixtral-8x7b", "--reduced", "--batch", "4",
         "--prompt-len", "32", "--gen", "16",

@@ -5,14 +5,13 @@ params, checkpointing, fault-tolerant loop.
 Run:  PYTHONPATH=src python examples/train_lm.py          (full xlstm-125m)
       PYTHONPATH=src python examples/train_lm.py --reduced --steps 50
 """
-import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from repro.launch.train import main  # noqa: E402
+from repro.launch.train import main
+from repro.runtime.compile_cache import use_compile_cache
 
 if __name__ == "__main__":
+    use_compile_cache()
     argv = sys.argv[1:] or [
         "--arch", "xlstm-125m", "--steps", "300", "--batch", "8",
         "--seq", "128", "--lr", "3e-3", "--log-every", "20",

@@ -191,8 +191,11 @@ class Kernel(Protocol):
 
 
 class _PlacedKernel:
-    """Shared placement logic: hold this kernel's sub-mesh and stage inputs
-    onto its first device when a real (non-time-shared) partition is bound.
+    """Shared placement logic: hold this kernel's sub-mesh and, when a real
+    (non-time-shared) partition is bound, run on its first device — frames
+    are staged there per call, parameter trees are moved there once per
+    tree version (:meth:`_place`), so a retrained student crosses to the
+    B-SA once per weight update, not once per call.
 
     Kernels also know how to read a resolved
     :class:`~repro.core.decision.SpatialPlan`: each kernel picks its own
@@ -205,9 +208,14 @@ class _PlacedKernel:
     role = "t_sa"
     precision_field = "retraining"  # which PrecisionPolicy field this reads
 
+    _PLACED_MAX = 8  # parameter-tree versions kept on the device (LRU)
+
     def __init__(self):
         self.submesh = None
         self._device = None
+        # id(source tree) -> (source tree, copy on self._device); the strong
+        # reference pins the id, as in ServingParamsCache.
+        self._placed: "OrderedDict[int, tuple]" = OrderedDict()
         self.n_apply_calls = 0  # jitted-dispatch counter (bench/tests)
 
     # --------------------------------------------------- spatial-plane view
@@ -228,6 +236,7 @@ class _PlacedKernel:
                                     self.plan_precision(spatial))
 
     def bind_partition(self, partition: SpatialPartition) -> None:
+        self._placed.clear()
         if partition.time_shared:
             self.submesh, self._device = None, None
             return
@@ -238,9 +247,26 @@ class _PlacedKernel:
     def _put(self, x):
         return x if self._device is None else jax.device_put(x, self._device)
 
+    def _place(self, tree):
+        """``tree`` on this kernel's device, moved once per tree version:
+        JAX arrays are immutable, so the source tree's identity is its
+        version and a repeat call is a lookup."""
+        if self._device is None:
+            return tree
+        key = id(tree)
+        entry = self._placed.get(key)
+        if entry is not None and entry[0] is tree:
+            self._placed.move_to_end(key)
+            return entry[1]
+        placed = jax.device_put(tree, self._device)
+        self._placed[key] = (tree, placed)
+        while len(self._placed) > self._PLACED_MAX:
+            self._placed.popitem(last=False)
+        return placed
+
     def _run_apply(self, params, x):
         self.n_apply_calls += 1
-        return self._apply(params, self._put(x))
+        return self._apply(self._place(params), self._put(x))
 
 
 class InferenceKernel(_PlacedKernel):
@@ -337,7 +363,8 @@ class InferenceKernel(_PlacedKernel):
                 [w, np.zeros((n_max - len(w),) + w.shape[1:], w.dtype)])
             for w in windows])
         stacked = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves), *params_list)
+            lambda *leaves: jnp.stack(leaves),
+            *[self._place(p) for p in params_list])
         if self._apply_fleet is None:
             self._apply_fleet = jax.jit(jax.vmap(self.model.apply))
         self.n_apply_calls += 1
@@ -460,6 +487,7 @@ class RetrainKernel(_PlacedKernel):
         self.estimator = estimator
         self.hp = hp
         self._step = jax.jit(self._sgd_step)
+        self.last_loss = None  # loss of the last SGD step, on the device
         # Serving caches to invalidate when retraining supersedes a tree
         # (the session wires the inference kernel's cache in here).
         self.invalidates: Tuple[ServingParamsCache, ...] = ()
@@ -495,15 +523,19 @@ class RetrainKernel(_PlacedKernel):
         stale hits impossible anyway — this reclaims the entries)."""
         for cache in self.invalidates:
             cache.invalidate(params)
+        # Master weights and optimizer state live on the T-SA device: a
+        # no-op once they are there (every fit after the first).
+        params, opt = self._put(params), self._put(opt)
         hp = self.hp
         n_batches = 0
         for _ in range(epochs if epochs is not None else hp.epochs):
             perm = rng.permutation(len(xt))
             for i in range(0, len(xt) - hp.sgd_batch + 1, hp.sgd_batch):
                 idx = perm[i: i + hp.sgd_batch]
-                params, opt, _ = self._step(params, opt, self._put(xt[idx]),
-                                            self._put(yt[idx]))
+                params, opt, self.last_loss = self._step(
+                    params, opt, self._put(xt[idx]), self._put(yt[idx]))
                 n_batches += 1
+                self.n_apply_calls += 1
         return params, opt, n_batches
 
     def time_per_batch(self, rows: int, precision: str) -> float:

@@ -3,10 +3,14 @@
 Methodology mirrors the paper's evaluation split (§VII-A): the *virtual
 clock* advances by phase durations computed from the performance estimator on
 the FULL model configs (Table III / Table IV hardware), while the *learning
-dynamics* (inference, labeling, retraining, accuracy) execute on reduced
-same-family twins over the synthetic drift stream — "integrating hardware
-simulation and GPU kernel execution" exactly as the paper's system simulator
-does, with JAX/CPU in the GPU role.
+dynamics* (inference, labeling, retraining, accuracy) execute over the
+synthetic drift stream — "integrating hardware simulation and GPU kernel
+execution" exactly as the paper's system simulator does, with JAX in the GPU
+role. By default the executed models are reduced same-family twins
+(``VisionConfig.reduced``: 24-px frames, 8 classes) so the loop runs on a
+CPU; ``reduced=False`` executes the configs as given (published widths,
+224-px frames, 1000-class heads) — the estimator reads the full configs
+either way, so the virtual clock is the same.
 
 Layering (see ROADMAP.md "Architecture"):
 
@@ -227,6 +231,7 @@ class CLSession:
         speculative_frames: Optional[bool] = None,
         decision_aware_spec: bool = True,
         trace: Union[None, bool, TraceRecorder] = None,
+        reduced: bool = True,
     ):
         self.hp = hp or CLHyperParams()
         self.estimator = estimator or DaCapoEstimator()
@@ -272,8 +277,12 @@ class CLSession:
         else:
             self._label_microbatch = label_microbatch or None
         self.full_student, self.full_teacher = student_cfg, teacher_cfg
-        self.student_cfg = student_cfg.reduced()
-        self.teacher_cfg = teacher_cfg.reduced()
+        # What executes: the reduced twins (CPU-sized, the default) or the
+        # configs as given. The estimator prices the full configs either way.
+        if reduced:
+            student_cfg, teacher_cfg = (student_cfg.reduced(),
+                                        teacher_cfg.reduced())
+        self.student_cfg, self.teacher_cfg = student_cfg, teacher_cfg
         self.student = make_vision_model(self.student_cfg)
         self.teacher = make_vision_model(self.teacher_cfg)
         self.seed = seed
@@ -592,7 +601,9 @@ class CLSystemSpec:
     ``estimator`` accepts an instance or a zero-arg factory (class/lambda);
     ``allocator`` accepts a registry name, an ``AllocationPolicy`` class, or
     a ready instance. ``student``/``teacher`` are the FULL paper configs
-    (Table III); the session derives the reduced twins itself.
+    (Table III); with ``reduced=True`` (default) the session executes their
+    reduced twins, with ``reduced=False`` the configs as given — then feed
+    it a stream at the configs' ``img_size`` (``DriftStream(..., img=224)``).
 
         spec = CLSystemSpec(student=RESNET18, teacher=WIDERESNET50,
                             allocator="ekya", apply_mx=False)
@@ -619,6 +630,8 @@ class CLSystemSpec:
     # Trace spine: None = off (bit-identical), True = fresh TraceRecorder,
     # or a ready TraceRecorder instance to share. See core/trace.py.
     trace: Union[None, bool, TraceRecorder] = None
+    # Execute the reduced twins (True) or the configs as given (False).
+    reduced: bool = True
 
     def _session_kwargs(self) -> dict:
         """The resolved CLSession constructor kwargs this spec describes —
@@ -646,6 +659,7 @@ class CLSystemSpec:
             speculative_frames=self.speculative_frames,
             decision_aware_spec=self.decision_aware_spec,
             trace=self.trace,
+            reduced=self.reduced,
         )
 
     def build(self) -> CLSession:

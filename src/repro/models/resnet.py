@@ -1,8 +1,9 @@
 """ResNet / WideResNet (paper Table III students & teachers) in pure JAX.
 
 GroupNorm replaces BatchNorm (no mutable running stats in the functional CL
-loop; equivalent behaviour at these scales — noted in DESIGN.md). Params are
-pure-array pytrees; the static block plan is derived from the config.
+loop) — the one departure from the published architectures, at every width.
+Params are pure-array pytrees; the static block plan is derived from the
+config.
 """
 from __future__ import annotations
 

@@ -473,6 +473,106 @@ def test_engine_partitions_fake_mesh(golden_setup):
     assert flat.inference.submesh is None
 
 
+# Runs in a child process: the CPU device count is fixed when JAX starts.
+_FISSION_CHILD = r"""
+import json
+import jax
+import numpy as np
+from repro.configs.dacapo_pairs import RESNET18, WIDERESNET50
+from repro.core.allocation import CLHyperParams
+from repro.core.partition import forced_row_mesh
+from repro.core.session import CLSystemSpec, pretrain_model
+from repro.data.stream import DriftStream, scenario
+from repro.models.registry import make_vision_model
+
+assert jax.device_count() == 4, jax.devices()
+stream = DriftStream(scenario("S1", 3), seed=5, img=24)
+hp = CLHyperParams(n_t=48, n_l=24, c_b=192, epochs=1)
+rng = np.random.default_rng(0)
+tp = pretrain_model(make_vision_model(WIDERESNET50.reduced()), stream, 10,
+                    16, rng)
+sp = pretrain_model(make_vision_model(RESNET18.reduced()), stream, 5, 16,
+                    rng, segments=stream.segments[:1], seed=8)
+out = {}
+for name, mesh in (("fission", forced_row_mesh(4)), ("flat", None)):
+    session = CLSystemSpec(student=RESNET18, teacher=WIDERESNET50, hp=hp,
+                           eval_fps=0.5, dispatch="concurrent",
+                           mesh=mesh).build()
+    session.set_pretrained(tp, sp)
+    res = session.run(stream, duration=30.0)
+    out[name] = {"phases": [[r.acc_valid, r.acc_label] for r in res.records],
+                 "timeline": res.accuracy_timeline}
+    if mesh is None:
+        continue
+    x = stream.frames(0.0, 1.0, max_frames=8)[0]
+    prec = session.policy
+    served = session.inference.predict_async(
+        session.inference.serving_params(session.student_params,
+                                         prec.inference), x)
+    labels = session.labeling.label_async(session.teacher_params, x,
+                                          prec.labeling)
+    leaves = jax.tree_util.tree_leaves(session.student_params)
+    ids = lambda arrays: sorted({d.id for a in arrays for d in a.devices()})
+    out["devices"] = {
+        "t_sa": session.partition.t_sa.devices.flat[0].id,
+        "b_sa": session.partition.b_sa.devices.flat[0].id,
+        "served": ids([served]), "labels": ids([labels]),
+        "retrained": ids(leaves + [session.retrain.last_loss])}
+print(json.dumps(out))
+"""
+
+
+def test_fission_on_four_devices_matches_one_device():
+    """T-SA/B-SA fission on four distinct (virtual CPU) devices: each
+    kernel's parameters follow it to its sub-accelerator's device, outputs
+    land there, and per-phase accuracies equal the same session on one
+    device (the same programs, only placed elsewhere)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in (src, os.environ.get("PYTHONPATH")) if p),
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                          + " --xla_force_host_platform_device_count=4"))
+    proc = subprocess.run([sys.executable, "-c", _FISSION_CHILD], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    dev = out["devices"]
+    assert dev["t_sa"] != dev["b_sa"]
+    assert dev["served"] == [dev["b_sa"]]
+    assert dev["labels"] == [dev["t_sa"]]
+    assert dev["retrained"] == [dev["t_sa"]]
+    assert len(out["fission"]["phases"]) >= 3
+    assert out["fission"] == out["flat"]
+
+
+def test_spec_published_widths_builds_unreduced():
+    """``reduced=False`` executes the configs as given (published widths,
+    224-px frames, 1000-class heads); the estimator prices the same full
+    configs either way, so the offline row split is unchanged. Built only:
+    nothing is initialized or run at this size on a CPU."""
+    spec = CLSystemSpec(student=RESNET18, teacher=WIDERESNET50,
+                        reduced=False, dispatch="concurrent")
+    session = spec.build()
+    assert session.student_cfg == RESNET18
+    assert session.teacher_cfg == WIDERESNET50
+    assert session.student.cfg.num_classes == 1000
+    assert session.teacher.cfg.img_size == 224
+    assert session.retrain.model is session.student
+    assert session.labeling.model is session.teacher
+    twins = dataclasses.replace(spec, reduced=True).build()
+    assert twins.student_cfg == RESNET18.reduced()
+    assert twins.teacher_cfg == WIDERESNET50.reduced()
+    assert (session.r_tsa, session.r_bsa) == (twins.r_tsa, twins.r_bsa)
+    assert session.full_student == twins.full_student == RESNET18
+
+
 def test_spec_is_declarative_and_replaceable(golden_setup):
     """Benchmark-style partial specs are completed via dataclasses.replace."""
     stream, hp, tp, sp = golden_setup
