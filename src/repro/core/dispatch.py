@@ -58,6 +58,9 @@ the unit count (samples/batches) the cost scales with — and every bare
 observational only (no numeric plan state is touched), so traced runs are
 bit-identical to untraced ones; with no recorder (the default) the traced
 overrides reduce to a single ``is None`` check and the original code path.
+Independently of the recorder, each program's issue runs in a profiler span
+``dacapo.issue.<label>`` and each materializing ``collect()`` in
+``dacapo.collect`` (:func:`~repro.core.trace.span`).
 The per-phase event order, the phase start/end/floor and the per-role
 float-add sequence are exactly what
 :class:`~repro.core.replay.TraceReplayer` replays to reconstruct — and
@@ -69,7 +72,7 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.core.trace import TraceEvent, TraceRecorder
+from repro.core.trace import TraceEvent, TraceRecorder, span
 
 import numpy as np
 
@@ -112,7 +115,8 @@ class ProgramHandle:
 
     def collect(self) -> np.ndarray:
         if not self._collected:
-            self._host = np.asarray(self._value)
+            with span("collect"):
+                self._host = np.asarray(self._value)
             self._value = None  # drop the device reference
             self._collected = True
         return self._host
@@ -179,7 +183,8 @@ class PhasePlan:
         ``units`` is the trace-facing quantity the cost was computed from
         (frames scored, samples labeled) — ignored untraced."""
         del units
-        handle = ProgramHandle(issue())
+        with span("issue." + label):
+            handle = ProgramHandle(issue())
         self.programs.append(DeviceProgram(role, label, cost_s, handle, lane))
         self.charge(role, cost_s, lane=lane)
         return handle
@@ -197,7 +202,8 @@ class PhasePlan:
         both the fleet ledger and that lane's ledger, in lane order — for a
         one-lane plan this is exactly a single ``dispatch``."""
         del units
-        values = issue()
+        with span("issue." + label):
+            values = issue()
         if len(values) != len(lanes) or len(costs) != len(lanes):
             raise ValueError(
                 f"dispatch_multi: {len(values)} values / {len(costs)} costs "
@@ -295,9 +301,7 @@ class KernelDispatcher:
     One dispatcher lives on a :class:`~repro.core.session.CLSession`; its
     mode decides the clock semantics of every :class:`PhasePlan` it opens
     (see module docstring). ``phases_dispatched`` / ``programs_dispatched``
-    are cumulative counters for benchmarks and tests;
-    ``programs_by_label`` breaks the program count down by dispatch label
-    (e.g. one batched ``"acc_label"`` program per fleet labeling burst).
+    are cumulative counters for benchmarks and tests.
 
     ``recorder`` (a :class:`~repro.core.trace.TraceRecorder`, default
     None) turns on the trace spine: each ``begin_phase`` opens a
@@ -315,8 +319,6 @@ class KernelDispatcher:
         self.recorder = recorder
         self.phases_dispatched = 0
         self.programs_dispatched = 0
-        self.windows_fetched = 0
-        self.programs_by_label: Dict[str, int] = {}
 
     @property
     def concurrent(self) -> bool:
@@ -387,8 +389,6 @@ class _TrackedPlan(PhasePlan):
                  lane: Optional[int] = None,
                  units: float = 0.0) -> ProgramHandle:
         self._dispatcher.programs_dispatched += 1
-        by_label = self._dispatcher.programs_by_label
-        by_label[label] = by_label.get(label, 0) + 1
         tr = self._trace
         if tr is None:
             return super().dispatch(role, label, issue, cost_s, lane=lane)
@@ -414,8 +414,6 @@ class _TrackedPlan(PhasePlan):
                        units: Optional[Sequence[float]] = None
                        ) -> List[ProgramHandle]:
         self._dispatcher.programs_dispatched += 1
-        by_label = self._dispatcher.programs_by_label
-        by_label[label] = by_label.get(label, 0) + 1
         tr = self._trace
         if tr is None:
             return super().dispatch_multi(role, label, issue, costs, lanes)
@@ -457,8 +455,3 @@ class _TrackedPlan(PhasePlan):
             tr.end = end
             tr.floor = self._floor
         return end
-
-    def fetch(self, t0: float, t1: float, max_frames: int = 0,
-              lane: int = 0, tag: Optional[str] = None):
-        self._dispatcher.windows_fetched += 1
-        return super().fetch(t0, t1, max_frames, lane=lane, tag=tag)

@@ -77,6 +77,7 @@ from repro.core.session import (
     _ScoreSink,
     flush_sinks_batched,
 )
+from repro.core.trace import span
 from repro.data.pipeline import FramePipeline
 from repro.data.stream import DriftStream
 from repro.runtime.elastic import rehome_tree
@@ -390,66 +391,72 @@ class FleetRun:
         decisions = self.decisions
         clock = self.clock
 
-        if True:  # one while-body iteration of the pre-manager loop
+        with span("phase"):
             phase_start = clock
-            spatial = fleet_dec.spatial
-            self._spatial = spatial
-            temporal = fleet_dec.temporal
-            r_tsa, r_bsa = spatial.rows_tsa, spatial.rows_bsa
-            if spatial.refission:  # the fleet plane's re-fission intent
-                session._repartition(r_bsa)
-            for lane in lanes:
-                lane.decision = decisions[lane.index]
-                lane.keep_frac = session.inference.plan_keep_frac(
-                    spatial, hp.fps * n)
-            # ---- Plan: one shared ledger for the fleet phase; the plan
-            # consumes the fleet decision's per-lane views — rotating every
-            # lane's speculation, pre-sized with its temporal budget. ----
-            plan = session.dispatcher.begin_phase(
-                clock, pipes, decisions=fleet_dec.per_lane(),
-                fps=hp.fps if session.decision_aware_spec else None)
-            for lane in lanes:
-                lane.spec_seen = (lane.pipe.hits, lane.pipe.misses)
-                lane.valid_h = lane.yv = None
-                lane.acc_v = 1.0
-                if temporal[lane.index].profile_cost_s:
-                    plan.charge("t_sa", temporal[lane.index].profile_cost_s,
-                                lane=lane.index, label="profile")
+            with span("plan"):
+                spatial = fleet_dec.spatial
+                self._spatial = spatial
+                temporal = fleet_dec.temporal
+                r_tsa, r_bsa = spatial.rows_tsa, spatial.rows_bsa
+                if spatial.refission:  # the fleet plane's re-fission intent
+                    session._repartition(r_bsa)
+                for lane in lanes:
+                    lane.decision = decisions[lane.index]
+                    lane.keep_frac = session.inference.plan_keep_frac(
+                        spatial, hp.fps * n)
+                # ---- Plan: one shared ledger for the fleet phase; the plan
+                # consumes the fleet decision's per-lane views — rotating
+                # every lane's speculation, pre-sized with its temporal
+                # budget. ----
+                plan = session.dispatcher.begin_phase(
+                    clock, pipes, decisions=fleet_dec.per_lane(),
+                    fps=hp.fps if session.decision_aware_spec else None)
+                for lane in lanes:
+                    lane.spec_seen = (lane.pipe.hits, lane.pipe.misses)
+                    lane.valid_h = lane.yv = None
+                    lane.acc_v = 1.0
+                    if temporal[lane.index].profile_cost_s:
+                        plan.charge("t_sa",
+                                    temporal[lane.index].profile_cost_s,
+                                    lane=lane.index, label="profile")
             # -------- Retraining (Alg. 1 lines 4-7), lane by lane on the
             # shared T-SA chain --------
-            for lane in lanes:
-                t_lane = temporal[lane.index]
-                if (len(lane.buffer) >= hp.sgd_batch
-                        and t_lane.retrain_samples > 0):
-                    xt, yt, xv, yv = lane.buffer.get_data(
-                        t_lane.retrain_samples, t_lane.valid_samples)
-                    fit_t0 = time.perf_counter() if plan.traced else 0.0
-                    lane.params, lane.opt, n_batches = session.retrain.fit(
-                        lane.params, lane.opt, xt, yt, lane.rng,
-                        epochs=t_lane.retrain_epochs)
-                    t_phase = n_batches * session.retrain.plan_time_per_batch(
-                        spatial)
-                    plan.charge(
-                        "t_sa", t_phase, lane=lane.index, label="retrain",
-                        units=n_batches,
-                        wall_s=(time.perf_counter() - fit_t0 if plan.traced
-                                else 0.0))
-                    lane.retrain_time += t_phase
-                    lane.serving = session.inference.serving_params(
-                        lane.params, spatial.precisions.inference)
-                    lane.yv = yv
-                    v_role = ("b_sa" if session.dispatcher.concurrent
-                              else "t_sa")
-                    lane.valid_h = plan.dispatch(
-                        v_role, "valid",
-                        lambda s=lane.serving, v=xv:
-                        session.inference.predict_async(s, v),
-                        cost_s=len(xv) * session.inference.plan_time_per_sample(
-                            spatial, role=v_role),
-                        lane=lane.index, units=len(xv))
-            for lane in lanes:
-                self._score_lane_until(lane, min(plan.now(), duration),
-                                       lane.serving, plan)
+            with span("retrain"):
+                for lane in lanes:
+                    t_lane = temporal[lane.index]
+                    if (len(lane.buffer) >= hp.sgd_batch
+                            and t_lane.retrain_samples > 0):
+                        xt, yt, xv, yv = lane.buffer.get_data(
+                            t_lane.retrain_samples, t_lane.valid_samples)
+                        fit_t0 = time.perf_counter() if plan.traced else 0.0
+                        lane.params, lane.opt, n_batches = session.retrain.fit(
+                            lane.params, lane.opt, xt, yt, lane.rng,
+                            epochs=t_lane.retrain_epochs)
+                        t_phase = (n_batches * session.retrain
+                                   .plan_time_per_batch(spatial))
+                        plan.charge(
+                            "t_sa", t_phase, lane=lane.index, label="retrain",
+                            units=n_batches,
+                            wall_s=(time.perf_counter() - fit_t0 if plan.traced
+                                    else 0.0))
+                        lane.retrain_time += t_phase
+                        lane.serving = session.inference.serving_params(
+                            lane.params, spatial.precisions.inference)
+                        lane.yv = yv
+                        v_role = ("b_sa" if session.dispatcher.concurrent
+                                  else "t_sa")
+                        lane.valid_h = plan.dispatch(
+                            v_role, "valid",
+                            lambda s=lane.serving, v=xv:
+                            session.inference.predict_async(s, v),
+                            cost_s=len(xv)
+                            * session.inference.plan_time_per_sample(
+                                spatial, role=v_role),
+                            lane=lane.index, units=len(xv))
+            with span("score"):
+                for lane in lanes:
+                    self._score_lane_until(lane, min(plan.now(), duration),
+                                           lane.serving, plan)
             if plan.now() >= duration:
                 self.clock = plan.finish()
                 self.done = True
@@ -457,135 +464,138 @@ class FleetRun:
 
             # -------- Labeling (lines 8-10): bursts fetched per lane, then
             # batched across the fleet on the shared T-SA --------
-            for lane in lanes:
-                if temporal[lane.index].reset_buffer:
-                    lane.buffer.reset()  # line 12
-                    lane.drift_events += 1
-            t_lab0 = plan.now()
-            for lane in lanes:
-                n_label = temporal[lane.index].total_label_samples
-                lane.x_l, _ = plan.fetch(t_lab0, t_lab0 + n_label / hp.fps,
-                                         max_frames=n_label,
-                                         lane=lane.index, tag="label")
-            # ONE batched device program labels the whole fleet's burst at
-            # the fleet spatial plane's labeling precision (cross-stream
-            # microbatches on the shared T-SA).
-            costs = [
-                temporal[lane.index].total_label_samples
-                * session.labeling.plan_time_per_sample(spatial)
-                for lane in lanes]
-            t_run = plan.now()
-            handles = plan.dispatch_multi(
-                "t_sa", "label",
-                lambda: session.labeling.label_fleet_async(
-                    session.teacher_params, [ln.x_l for ln in lanes],
-                    spatial.precisions.labeling,
-                    microbatch=session._label_microbatch),
-                costs=costs, lanes=[lane.index for lane in lanes],
-                units=[float(temporal[lane.index].total_label_samples)
-                       for lane in lanes])
-            for lane, handle, cost in zip(lanes, handles, costs):
-                # Replay the plan's serial accumulation so each lane's
-                # label_time reproduces the single-stream float pattern
-                # ((t + c) - t), which the degeneracy golden pins.
-                t_next = t_run + cost
-                lane.label_time += t_next - t_run
-                t_run = t_next
-                lane.label_h = handle
-            for lane in lanes:
-                lane.pred_l_h = plan.dispatch(
-                    "b_sa", "acc_label",
-                    lambda s=lane.serving, x=lane.x_l:
-                    session.inference.predict_async(s, x),
-                    cost_s=len(lane.x_l)
-                    * session.inference.plan_time_per_sample(spatial),
-                    lane=lane.index, units=len(lane.x_l))
-            for lane in lanes:
-                self._score_lane_until(lane, min(plan.now(), duration),
-                                       lane.serving, plan)
+            with span("label"):
+                for lane in lanes:
+                    if temporal[lane.index].reset_buffer:
+                        lane.buffer.reset()  # line 12
+                        lane.drift_events += 1
+                t_lab0 = plan.now()
+                for lane in lanes:
+                    n_label = temporal[lane.index].total_label_samples
+                    lane.x_l, _ = plan.fetch(t_lab0, t_lab0 + n_label / hp.fps,
+                                             max_frames=n_label,
+                                             lane=lane.index, tag="label")
+                # ONE batched device program labels the whole fleet's burst at
+                # the fleet spatial plane's labeling precision (cross-stream
+                # microbatches on the shared T-SA).
+                costs = [
+                    temporal[lane.index].total_label_samples
+                    * session.labeling.plan_time_per_sample(spatial)
+                    for lane in lanes]
+                t_run = plan.now()
+                handles = plan.dispatch_multi(
+                    "t_sa", "label",
+                    lambda: session.labeling.label_fleet_async(
+                        session.teacher_params, [ln.x_l for ln in lanes],
+                        spatial.precisions.labeling,
+                        microbatch=session._label_microbatch),
+                    costs=costs, lanes=[lane.index for lane in lanes],
+                    units=[float(temporal[lane.index].total_label_samples)
+                           for lane in lanes])
+                for lane, handle, cost in zip(lanes, handles, costs):
+                    # Replay the plan's serial accumulation so each lane's
+                    # label_time reproduces the single-stream float pattern
+                    # ((t + c) - t), which the degeneracy golden pins.
+                    t_next = t_run + cost
+                    lane.label_time += t_next - t_run
+                    t_run = t_next
+                    lane.label_h = handle
+                for lane in lanes:
+                    lane.pred_l_h = plan.dispatch(
+                        "b_sa", "acc_label",
+                        lambda s=lane.serving, x=lane.x_l:
+                        session.inference.predict_async(s, x),
+                        cost_s=len(lane.x_l)
+                        * session.inference.plan_time_per_sample(spatial),
+                        lane=lane.index, units=len(lane.x_l))
+            with span("score"):
+                for lane in lanes:
+                    self._score_lane_until(lane, min(plan.now(), duration),
+                                           lane.serving, plan)
 
-            # Fixed-window pacing, per lane temporal plane (the pacing
-            # floor is the max boundary any paced lane declares).
-            for lane in lanes:
-                if temporal[lane.index].pace_window_s:
-                    w = temporal[lane.index].pace_window_s
-                    next_boundary = (int(phase_start / w) + 1) * w
-                    if plan.now() < next_boundary:
-                        self._score_lane_until(
-                            lane, min(next_boundary, duration),
-                            lane.serving, plan)
-                        plan.pad_to(next_boundary)
+                # Fixed-window pacing, per lane temporal plane (the pacing
+                # floor is the max boundary any paced lane declares).
+                for lane in lanes:
+                    if temporal[lane.index].pace_window_s:
+                        w = temporal[lane.index].pace_window_s
+                        next_boundary = (int(phase_start / w) + 1) * w
+                        if plan.now() < next_boundary:
+                            self._score_lane_until(
+                                lane, min(next_boundary, duration),
+                                lane.serving, plan)
+                            plan.pad_to(next_boundary)
 
             # ---- Collect: the fleet phase-end barrier. ----
-            clock = plan.finish()
-            self.clock = clock
-            serve_batched = session.fleet_serve_batched
-            for lane in lanes:
-                self._score_lane_until(lane, min(clock, duration),
-                                       lane.serving, None)
-                if lane.valid_h is not None:
-                    lane.acc_v = float(
-                        (lane.valid_h.collect() == lane.yv).mean())
-                y_l = lane.label_h.collect()
-                lane.acc_l = float(
-                    (lane.pred_l_h.collect() == y_l).mean())
-                lane.buffer.update(lane.x_l, y_l)  # line 14
-                if not serve_batched:
-                    lane.sink.flush()
-            if serve_batched:
-                # One vmapped B-SA program serves every lane's queued
-                # score windows (ledger already charged per window).
-                flush_sinks_batched(session.inference,
-                                    [ln.sink for ln in lanes])
+            with span("barrier"):
+                clock = plan.finish()
+                self.clock = clock
+                serve_batched = session.fleet_serve_batched
+                for lane in lanes:
+                    self._score_lane_until(lane, min(clock, duration),
+                                           lane.serving, None)
+                    if lane.valid_h is not None:
+                        lane.acc_v = float(
+                            (lane.valid_h.collect() == lane.yv).mean())
+                    y_l = lane.label_h.collect()
+                    lane.acc_l = float(
+                        (lane.pred_l_h.collect() == y_l).mean())
+                    lane.buffer.update(lane.x_l, y_l)  # line 14
+                    if not serve_batched:
+                        lane.sink.flush()
+                if serve_batched:
+                    # One vmapped B-SA program serves every lane's queued
+                    # score windows (ledger already charged per window).
+                    flush_sinks_batched(session.inference,
+                                        [ln.sink for ln in lanes])
 
             # -------- Next decisions (lines 11-13), fleet-proportioned ----
             # Per-lane engine-side drift verdicts: computed once here (by
             # each lane policy's detector) and handed down on the feedback
             # — the deduped source the lane policies, the drift-weighted
             # split AND the fleet row policy all read.
-            feedbacks = [
-                PhaseFeedback(acc_valid=lane.acc_v, acc_label=lane.acc_l,
-                              t=clock, phase_start=phase_start,
-                              retrain_time=lane.retrain_time,
-                              label_time=lane.label_time,
-                              drifted=session.fleet_allocator.policies[
-                                  lane.index].observe_drift(
-                                      lane.acc_l, lane.acc_v, clock))
-                for lane in lanes]
-            next_fleet = session.fleet_allocator.next_fleet_decision(feedbacks)
-            next_decisions = list(next_fleet.lane_decisions)
-            self.fleet_phase_log.append({
-                "t": clock, "phase_start": phase_start,
-                "t_tsa": plan.t_tsa, "t_bsa": plan.t_bsa,
-                "rows_tsa": r_tsa, "rows_bsa": r_bsa,
-                "per_stream_t_tsa": [plan.lane_time("t_sa", lane.index)
-                                     for lane in lanes],
-                "per_stream_t_bsa": [plan.lane_time("b_sa", lane.index)
-                                     for lane in lanes],
-            })
-            for lane in lanes:
-                record = PhaseRecord(
-                    index=len(lane.records), t=clock, acc_valid=lane.acc_v,
-                    acc_label=lane.acc_l,
-                    drift=next_decisions[lane.index].reset_buffer,
-                    retrain_time=lane.retrain_time,
-                    label_time=lane.label_time,
-                    decision=lane.decision,
-                    next_decision=next_decisions[lane.index],
-                    phase_start=phase_start,
-                    t_tsa=plan.lane_time("t_sa", lane.index),
-                    t_bsa=plan.lane_time("b_sa", lane.index),
-                    spec_hits=lane.pipe.hits - lane.spec_seen[0],
-                    spec_misses=lane.pipe.misses - lane.spec_seen[1],
-                    stream=lane.index)
-                lane.records.append(record)
-                for obs in self.observers:
-                    obs(record)
-            self.fleet_dec = next_fleet
-            self.decisions = next_decisions
+            with span("decide"):
+                feedbacks = [
+                    PhaseFeedback(acc_valid=lane.acc_v, acc_label=lane.acc_l,
+                                  t=clock, phase_start=phase_start,
+                                  retrain_time=lane.retrain_time,
+                                  label_time=lane.label_time,
+                                  drifted=session.fleet_allocator.policies[
+                                      lane.index].observe_drift(
+                                          lane.acc_l, lane.acc_v, clock))
+                    for lane in lanes]
+                next_fleet = session.fleet_allocator.next_fleet_decision(
+                    feedbacks)
+                next_decisions = list(next_fleet.lane_decisions)
+                self.fleet_phase_log.append({
+                    "t": clock, "phase_start": phase_start,
+                    "t_tsa": plan.t_tsa, "t_bsa": plan.t_bsa,
+                    "rows_tsa": r_tsa, "rows_bsa": r_bsa,
+                    "per_stream_t_tsa": [plan.lane_time("t_sa", lane.index)
+                                         for lane in lanes],
+                    "per_stream_t_bsa": [plan.lane_time("b_sa", lane.index)
+                                         for lane in lanes],
+                })
+                for lane in lanes:
+                    record = PhaseRecord(
+                        index=len(lane.records), t=clock, acc_valid=lane.acc_v,
+                        acc_label=lane.acc_l,
+                        drift=next_decisions[lane.index].reset_buffer,
+                        retrain_time=lane.retrain_time,
+                        label_time=lane.label_time,
+                        decision=lane.decision,
+                        next_decision=next_decisions[lane.index],
+                        phase_start=phase_start,
+                        t_tsa=plan.lane_time("t_sa", lane.index),
+                        t_bsa=plan.lane_time("b_sa", lane.index),
+                        spec_hits=lane.pipe.hits - lane.spec_seen[0],
+                        spec_misses=lane.pipe.misses - lane.spec_seen[1],
+                        stream=lane.index)
+                    lane.records.append(record)
+                    for obs in self.observers:
+                        obs(record)
+                self.fleet_dec = next_fleet
+                self.decisions = next_decisions
         return True
-
-        raise AssertionError("unreachable")
 
     def finalize(self) -> FleetResult:
         """Score every lane to the duration and assemble the

@@ -21,7 +21,13 @@ the classic host-returning methods are thin ``np.asarray`` wrappers kept for
 callers outside the hot path. ``predict_batched`` fuses several frame
 windows into one jitted apply; ``label_async`` optionally microbatches large
 labeling bursts so each chunk starts executing while the next is staged.
-Every jitted apply invocation bumps ``n_apply_calls`` (bench/test counter).
+Every jitted apply invocation bumps ``n_apply_calls`` (bench/test counter),
+and ``h2d_bytes`` counts the bytes of the host (numpy) arrays a kernel hands
+to a jitted program or to ``device_put`` — frames, SGD labels, the fleet
+and labeling concatenations; device-resident arrays count nothing. The
+retraining loop and the serving-copy fill run in profiler spans
+(``dacapo.fit``, ``dacapo.fit.gather``, ``dacapo.fit.step``,
+``dacapo.quantize``; see core/trace.py).
 """
 from __future__ import annotations
 
@@ -36,6 +42,7 @@ import numpy as np
 from repro.configs.dacapo_pairs import VisionConfig
 from repro.core import mx as mx_lib
 from repro.core.partition import SpatialPartition
+from repro.core.trace import span
 
 
 class _CacheSlot:
@@ -139,15 +146,18 @@ class ServingParamsCache:
         callable stores its return value directly (test/bench hook)."""
         slot = self._claim(params, precision)
         with slot.lock:
-            if slot.value is None and slot.quantized is None:
-                self._count_fill()
-                if quantize is not None:
-                    slot.value = quantize(params, precision)
-                else:
-                    slot.quantized = mx_lib.quantize_tree_mx(params,
-                                                             precision)
             if slot.value is None:
-                slot.value = mx_lib.dequantize_tree_mx(slot.quantized)
+                with span("quantize"):
+                    if slot.quantized is None:
+                        self._count_fill()
+                        if quantize is not None:
+                            slot.value = quantize(params, precision)
+                        else:
+                            slot.quantized = mx_lib.quantize_tree_mx(
+                                params, precision)
+                    if slot.value is None:
+                        slot.value = mx_lib.dequantize_tree_mx(
+                            slot.quantized)
             return slot.value
 
     def get_quantized(self, params, precision: str):
@@ -156,8 +166,10 @@ class ServingParamsCache:
         slot = self._claim(params, precision)
         with slot.lock:
             if slot.quantized is None:
-                self._count_fill()
-                slot.quantized = mx_lib.quantize_tree_mx(params, precision)
+                with span("quantize"):
+                    self._count_fill()
+                    slot.quantized = mx_lib.quantize_tree_mx(params,
+                                                             precision)
             return slot.quantized
 
     def invalidate(self, params=None) -> None:
@@ -217,6 +229,7 @@ class _PlacedKernel:
         # reference pins the id, as in ServingParamsCache.
         self._placed: "OrderedDict[int, tuple]" = OrderedDict()
         self.n_apply_calls = 0  # jitted-dispatch counter (bench/tests)
+        self.h2d_bytes = 0  # bytes of host arrays handed to the device
 
     # --------------------------------------------------- spatial-plane view
     def plan_rows(self, spatial, role: Optional[str] = None) -> int:
@@ -247,6 +260,11 @@ class _PlacedKernel:
     def _put(self, x):
         return x if self._device is None else jax.device_put(x, self._device)
 
+    def _count_h2d(self, *arrays) -> None:
+        """Add the host (numpy) arrays among ``arrays`` to ``h2d_bytes``."""
+        self.h2d_bytes += sum(a.nbytes for a in arrays
+                              if isinstance(a, np.ndarray))
+
     def _place(self, tree):
         """``tree`` on this kernel's device, moved once per tree version:
         JAX arrays are immutable, so the source tree's identity is its
@@ -266,6 +284,7 @@ class _PlacedKernel:
 
     def _run_apply(self, params, x):
         self.n_apply_calls += 1
+        self._count_h2d(x)
         return self._apply(self._place(params), self._put(x))
 
 
@@ -368,6 +387,7 @@ class InferenceKernel(_PlacedKernel):
         if self._apply_fleet is None:
             self._apply_fleet = jax.jit(jax.vmap(self.model.apply))
         self.n_apply_calls += 1
+        self._count_h2d(padded)
         logits = self._apply_fleet(stacked, self._put(padded))
         preds = jnp.argmax(logits, -1)
         return [preds[i, :size] for i, size in enumerate(sizes)]
@@ -523,19 +543,25 @@ class RetrainKernel(_PlacedKernel):
         stale hits impossible anyway — this reclaims the entries)."""
         for cache in self.invalidates:
             cache.invalidate(params)
-        # Master weights and optimizer state live on the T-SA device: a
-        # no-op once they are there (every fit after the first).
-        params, opt = self._put(params), self._put(opt)
-        hp = self.hp
-        n_batches = 0
-        for _ in range(epochs if epochs is not None else hp.epochs):
-            perm = rng.permutation(len(xt))
-            for i in range(0, len(xt) - hp.sgd_batch + 1, hp.sgd_batch):
-                idx = perm[i: i + hp.sgd_batch]
-                params, opt, self.last_loss = self._step(
-                    params, opt, self._put(xt[idx]), self._put(yt[idx]))
-                n_batches += 1
-                self.n_apply_calls += 1
+        with span("fit"):
+            # Master weights and optimizer state live on the T-SA device: a
+            # no-op once they are there (every fit after the first).
+            params, opt = self._put(params), self._put(opt)
+            hp = self.hp
+            n_batches = 0
+            for _ in range(epochs if epochs is not None else hp.epochs):
+                perm = rng.permutation(len(xt))
+                for i in range(0, len(xt) - hp.sgd_batch + 1, hp.sgd_batch):
+                    with span("fit.gather"):
+                        idx = perm[i: i + hp.sgd_batch]
+                        xb, yb = xt[idx], yt[idx]
+                        self._count_h2d(xb, yb)
+                        xb, yb = self._put(xb), self._put(yb)
+                    with span("fit.step"):
+                        params, opt, self.last_loss = self._step(
+                            params, opt, xb, yb)
+                    n_batches += 1
+                    self.n_apply_calls += 1
         return params, opt, n_batches
 
     def time_per_batch(self, rows: int, precision: str) -> float:

@@ -15,6 +15,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.core.trace import span
+
 
 class SampleBuffer:
     def __init__(self, capacity: int, seed: int = 0):
@@ -33,14 +35,15 @@ class SampleBuffer:
     def update(self, x: np.ndarray, y: np.ndarray) -> None:
         """UpdateBuffer (Alg. 1 line 14): append, evict oldest beyond C_b."""
         assert len(x) == len(y)
-        if self._x is None:
-            self._x, self._y = np.asarray(x).copy(), np.asarray(y).copy()
-        else:
-            self._x = np.concatenate([self._x, x])
-            self._y = np.concatenate([self._y, y])
-        if len(self._x) > self.capacity:
-            self._x = self._x[-self.capacity:]
-            self._y = self._y[-self.capacity:]
+        with span("buffer.update"):
+            if self._x is None:
+                self._x, self._y = np.asarray(x).copy(), np.asarray(y).copy()
+            else:
+                self._x = np.concatenate([self._x, x])
+                self._y = np.concatenate([self._y, y])
+            if len(self._x) > self.capacity:
+                self._x = self._x[-self.capacity:]
+                self._y = self._y[-self.capacity:]
 
     def reset(self) -> None:
         """ResetBuffer (Alg. 1 line 12): drop outdated samples on drift."""
@@ -73,8 +76,9 @@ class SampleBuffer:
         n = len(self)
         if n == 0:
             raise ValueError("empty sample buffer")
-        idx = self._rng.permutation(n)
-        n_valid = min(n_valid, max(1, n // 5))
-        n_train = min(n_train, n - n_valid)
-        ti, vi = idx[:n_train], idx[n_train:n_train + n_valid]
-        return self._x[ti], self._y[ti], self._x[vi], self._y[vi]
+        with span("buffer.get"):
+            idx = self._rng.permutation(n)
+            n_valid = min(n_valid, max(1, n // 5))
+            n_train = min(n_train, n - n_valid)
+            ti, vi = idx[:n_train], idx[n_train:n_train + n_valid]
+            return self._x[ti], self._y[ti], self._x[vi], self._y[vi]

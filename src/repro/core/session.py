@@ -84,7 +84,7 @@ from repro.core.partition import (
     single_device_partition,
 )
 from repro.core.sample_buffer import SampleBuffer
-from repro.core.trace import TraceRecorder
+from repro.core.trace import TraceRecorder, span
 from repro.data.pipeline import FramePipeline
 from repro.data.stream import DriftStream
 from repro.models.registry import make_vision_model
@@ -159,7 +159,8 @@ class _ScoreSink:
 
     def add(self, t_end: float, x, y, keep_frac: float, params) -> None:
         if not self.fuse:
-            pred = self.kernel.predict_async(params, x)
+            with span("issue.score"):
+                pred = self.kernel.predict_async(params, x)
             self._entries.append((t_end, pred, y, keep_frac))
             return
         if self._pending and self._params is not params:
@@ -171,8 +172,9 @@ class _ScoreSink:
         """Dispatch queued windows (one fused jitted call) — still async."""
         if not self._pending:
             return
-        preds = self.kernel.predict_batched(
-            self._params, [x for _, x, _, _ in self._pending])
+        with span("issue.score"):
+            preds = self.kernel.predict_batched(
+                self._params, [x for _, x, _, _ in self._pending])
         for (t_end, _x, y, kf), pred in zip(self._pending, preds):
             self._entries.append((t_end, pred, y, kf))
         self._pending.clear()
@@ -180,8 +182,9 @@ class _ScoreSink:
     def timeline(self) -> List[Tuple[float, float]]:
         """Collect: materialize every queued prediction into (t, acc)."""
         self.flush()
-        return [(t_end, float((np.asarray(pred) == y).mean()) * kf)
-                for t_end, pred, y, kf in self._entries]
+        with span("collect"):
+            return [(t_end, float((np.asarray(pred) == y).mean()) * kf)
+                    for t_end, pred, y, kf in self._entries]
 
 
 def flush_sinks_batched(kernel: InferenceKernel,
@@ -198,10 +201,12 @@ def flush_sinks_batched(kernel: InferenceKernel,
         for sink in live:
             sink.flush()
         return
-    lane_windows = [np.concatenate([x for _, x, _, _ in s._pending], axis=0)
-                    for s in live]
-    preds = kernel.predict_fleet_async([s._params for s in live],
-                                       lane_windows)
+    with span("issue.score"):
+        lane_windows = [np.concatenate([x for _, x, _, _ in s._pending],
+                                       axis=0)
+                        for s in live]
+        preds = kernel.predict_fleet_async([s._params for s in live],
+                                           lane_windows)
     for sink, pred in zip(live, preds):
         off = 0
         for t_end, x, y, kf in sink._pending:
@@ -449,135 +454,158 @@ class CLSession:
             eval_cursor = t_end
 
         while clock < duration:
-            phase_start = clock
-            spatial = self._resolve_spatial(dec)
-            temporal = dec.temporal
-            prec = spatial.precisions
-            if spatial.refission:  # the plane's mesh re-fission intent
-                self._repartition(spatial.rows_bsa)
-            keep_frac = self.inference.plan_keep_frac(spatial, hp.fps)
-            # ---- Plan: open the phase ledger on the dispatcher; the plan
-            # consumes the Decision — rotating the pipeline's speculation
-            # onto this phase start, pre-sized with the temporal plane's
-            # labeling budget (the decision-aware predictor — the budget
-            # is known at the barrier, so drift-phase N_ldd bursts
-            # prefetch whole). ----
-            plan = self.dispatcher.begin_phase(
-                clock, pipe, decisions=(dec,),
-                fps=hp.fps if self.decision_aware_spec else None)
-            spec_seen = (pipe.hits, pipe.misses)
-            valid_h = xv = yv = None
-            # Profiling overhead (e.g. Ekya's per-window microprofiling)
-            # rides on the temporal plane and is charged to the T-SA ledger
-            # before the window's own work — zero for idealized policies.
-            if temporal.profile_cost_s:
-                plan.charge("t_sa", temporal.profile_cost_s, label="profile")
-            # ---------------- Retraining (Alg. 1 lines 4-7) ----------------
-            acc_v = 1.0
-            if len(buffer) >= hp.sgd_batch and temporal.retrain_samples > 0:
-                xt, yt, xv, yv = buffer.get_data(temporal.retrain_samples,
-                                                 temporal.valid_samples)
-                fit_t0 = time.perf_counter() if plan.traced else 0.0
-                self.student_params, self._opt, n_batches = self.retrain.fit(
-                    self.student_params, self._opt, xt, yt, self.rng,
-                    epochs=temporal.retrain_epochs)
-                t_phase = n_batches * self.retrain.plan_time_per_batch(
-                    spatial)
-                plan.charge(
-                    "t_sa", t_phase, label="retrain", units=n_batches,
-                    wall_s=(time.perf_counter() - fit_t0 if plan.traced
-                            else 0.0))
-                retrain_time += t_phase
-                # UpdateWeight + Valid (lines 6-7) — dispatched async; the
-                # accuracy is collected at the phase-end feedback barrier.
-                # Sequential keeps the seed's time-shared serial accounting
-                # (validation charged on the T-SA chain); concurrent places
-                # it where the inference kernel actually lives — the B-SA —
-                # so it overlaps the T-SA moving on to labeling.
-                serving = self.inference.serving_params(self.student_params,
-                                                        prec.inference)
-                v_role = ("b_sa" if self.dispatcher.concurrent else "t_sa")
-                valid_h = plan.dispatch(
-                    v_role, "valid",
-                    lambda s=serving, v=xv: self.inference.predict_async(s, v),
-                    cost_s=len(xv) * self.inference.plan_time_per_sample(
-                        spatial, role=v_role),
-                    units=len(xv))
-            score_until(min(plan.now(), duration), serving, plan)
-            if plan.now() >= duration:
-                clock = plan.finish()
-                break
+            with span("phase"):
+                phase_start = clock
+                # ---- Plan: open the phase ledger on the dispatcher; the
+                # plan consumes the Decision — rotating the pipeline's
+                # speculation onto this phase start, pre-sized with the
+                # temporal plane's labeling budget (the decision-aware
+                # predictor — the budget is known at the barrier, so
+                # drift-phase N_ldd bursts prefetch whole). ----
+                with span("plan"):
+                    spatial = self._resolve_spatial(dec)
+                    temporal = dec.temporal
+                    prec = spatial.precisions
+                    if spatial.refission:  # the plane's re-fission intent
+                        self._repartition(spatial.rows_bsa)
+                    keep_frac = self.inference.plan_keep_frac(spatial, hp.fps)
+                    plan = self.dispatcher.begin_phase(
+                        clock, pipe, decisions=(dec,),
+                        fps=hp.fps if self.decision_aware_spec else None)
+                    spec_seen = (pipe.hits, pipe.misses)
+                    valid_h = xv = yv = None
+                    # Profiling overhead (e.g. Ekya's per-window
+                    # microprofiling) rides on the temporal plane and is
+                    # charged to the T-SA ledger before the window's own
+                    # work — zero for idealized policies.
+                    if temporal.profile_cost_s:
+                        plan.charge("t_sa", temporal.profile_cost_s,
+                                    label="profile")
+                # --------------- Retraining (Alg. 1 lines 4-7) ---------------
+                acc_v = 1.0
+                with span("retrain"):
+                    if (len(buffer) >= hp.sgd_batch
+                            and temporal.retrain_samples > 0):
+                        xt, yt, xv, yv = buffer.get_data(
+                            temporal.retrain_samples, temporal.valid_samples)
+                        fit_t0 = time.perf_counter() if plan.traced else 0.0
+                        self.student_params, self._opt, n_batches = \
+                            self.retrain.fit(self.student_params, self._opt,
+                                             xt, yt, self.rng,
+                                             epochs=temporal.retrain_epochs)
+                        t_phase = n_batches * self.retrain.plan_time_per_batch(
+                            spatial)
+                        plan.charge(
+                            "t_sa", t_phase, label="retrain", units=n_batches,
+                            wall_s=(time.perf_counter() - fit_t0
+                                    if plan.traced else 0.0))
+                        retrain_time += t_phase
+                        # UpdateWeight + Valid (lines 6-7) — dispatched
+                        # async; the accuracy is collected at the phase-end
+                        # feedback barrier. Sequential keeps the seed's
+                        # time-shared serial accounting (validation charged
+                        # on the T-SA chain); concurrent places it where
+                        # the inference kernel actually lives — the B-SA —
+                        # so it overlaps the T-SA moving on to labeling.
+                        serving = self.inference.serving_params(
+                            self.student_params, prec.inference)
+                        v_role = ("b_sa" if self.dispatcher.concurrent
+                                  else "t_sa")
+                        valid_h = plan.dispatch(
+                            v_role, "valid",
+                            lambda s=serving, v=xv:
+                            self.inference.predict_async(s, v),
+                            cost_s=len(xv)
+                            * self.inference.plan_time_per_sample(
+                                spatial, role=v_role),
+                            units=len(xv))
+                with span("score"):
+                    score_until(min(plan.now(), duration), serving, plan)
+                if plan.now() >= duration:
+                    clock = plan.finish()
+                    break
 
-            # ---------------- Labeling (lines 8-10) ------------------------
-            n_label = temporal.total_label_samples
-            if temporal.reset_buffer:
-                buffer.reset()  # line 12
-                drift_events += 1
-            t_lab0 = plan.now()
-            x_l, _y_true = plan.fetch(t_lab0, t_lab0 + n_label / hp.fps,
-                                      max_frames=n_label, tag="label")
-            label_h = plan.dispatch(
-                "t_sa", "label",
-                lambda: self.labeling.label_async(
-                    self.teacher_params, x_l, prec.labeling,
-                    microbatch=self._label_microbatch),
-                cost_s=n_label * self.labeling.plan_time_per_sample(spatial),
-                units=n_label)
-            label_time += plan.now() - t_lab0
-            pred_l_h = plan.dispatch(
-                "b_sa", "acc_label",
-                lambda: self.inference.predict_async(serving, x_l),
-                cost_s=len(x_l) * self.inference.plan_time_per_sample(
-                    spatial),
-                units=len(x_l))
-            score_until(min(plan.now(), duration), serving, plan)
+                # --------------- Labeling (lines 8-10) -----------------------
+                with span("label"):
+                    n_label = temporal.total_label_samples
+                    if temporal.reset_buffer:
+                        buffer.reset()  # line 12
+                        drift_events += 1
+                    t_lab0 = plan.now()
+                    x_l, _y_true = plan.fetch(
+                        t_lab0, t_lab0 + n_label / hp.fps,
+                        max_frames=n_label, tag="label")
+                    label_h = plan.dispatch(
+                        "t_sa", "label",
+                        lambda: self.labeling.label_async(
+                            self.teacher_params, x_l, prec.labeling,
+                            microbatch=self._label_microbatch),
+                        cost_s=n_label
+                        * self.labeling.plan_time_per_sample(spatial),
+                        units=n_label)
+                    label_time += plan.now() - t_lab0
+                    pred_l_h = plan.dispatch(
+                        "b_sa", "acc_label",
+                        lambda: self.inference.predict_async(serving, x_l),
+                        cost_s=len(x_l)
+                        * self.inference.plan_time_per_sample(spatial),
+                        units=len(x_l))
+                with span("score"):
+                    score_until(min(plan.now(), duration), serving, plan)
+                    # Fixed-window pacing, declared by the temporal plane
+                    # (no baseline-specific branch: any policy may pace on
+                    # a grid).
+                    if temporal.pace_window_s:
+                        w = temporal.pace_window_s
+                        next_boundary = (int(phase_start / w) + 1) * w
+                        if plan.now() < next_boundary:
+                            score_until(min(next_boundary, duration),
+                                        serving, plan)
+                            plan.pad_to(next_boundary)
 
-            # Fixed-window pacing, declared by the temporal plane (no
-            # baseline-specific branch: any policy may pace on a grid).
-            if temporal.pace_window_s:
-                w = temporal.pace_window_s
-                next_boundary = (int(phase_start / w) + 1) * w
-                if plan.now() < next_boundary:
-                    score_until(min(next_boundary, duration), serving, plan)
-                    plan.pad_to(next_boundary)
+                # ---- Collect: the phase-end barrier — the only host sync.
+                with span("barrier"):
+                    clock = plan.finish()
+                    # Concurrent mode: when the B-SA dominates, the phase
+                    # end runs past the T-SA clock the score windows
+                    # tracked — score that tail now, under THIS phase's
+                    # serving params (uncharged: the phase end already
+                    # reflects the B-SA busy period). Sequential mode is a
+                    # no-op (clock == the last scored boundary).
+                    score_until(min(clock, duration), serving, None)
+                    if valid_h is not None:
+                        acc_v = float((valid_h.collect() == yv).mean())
+                    y_l = label_h.collect()
+                    acc_l = float((pred_l_h.collect() == y_l).mean())
+                    buffer.update(x_l, y_l)  # line 14
+                    sink.flush()  # fused scoring before serving params change
 
-            # ---- Collect: the phase-end barrier — the only host sync. ----
-            clock = plan.finish()
-            # Concurrent mode: when the B-SA dominates, the phase end runs
-            # past the T-SA clock the score windows tracked — score that
-            # tail now, under THIS phase's serving params (uncharged: the
-            # phase end already reflects the B-SA busy period). Sequential
-            # mode is a no-op (clock == the last scored boundary).
-            score_until(min(clock, duration), serving, None)
-            if valid_h is not None:
-                acc_v = float((valid_h.collect() == yv).mean())
-            y_l = label_h.collect()
-            acc_l = float((pred_l_h.collect() == y_l).mean())
-            buffer.update(x_l, y_l)  # line 14
-            sink.flush()  # issue fused scoring before serving params change
-
-            # ---------------- Next decision (lines 11-13) ------------------
-            # The engine-side drift verdict: computed once here, handed to
-            # the policy on the feedback (the deduped source of truth).
-            drifted = self.allocator.observe_drift(acc_l, acc_v, clock)
-            feedback = PhaseFeedback(
-                acc_valid=acc_v, acc_label=acc_l, t=clock,
-                phase_start=phase_start, retrain_time=retrain_time,
-                label_time=label_time, drifted=drifted)
-            next_raw = self.allocator.next_decision(feedback)
-            next_dec = as_decision(next_raw)
-            record = PhaseRecord(
-                index=len(records), t=clock, acc_valid=acc_v,
-                acc_label=acc_l, drift=next_dec.temporal.reset_buffer,
-                retrain_time=retrain_time, label_time=label_time,
-                decision=raw, next_decision=next_raw,
-                phase_start=phase_start, t_tsa=plan.t_tsa, t_bsa=plan.t_bsa,
-                spec_hits=pipe.hits - spec_seen[0],
-                spec_misses=pipe.misses - spec_seen[1])
-            records.append(record)
-            for obs in observers:
-                obs(record)
-            raw, dec = next_raw, next_dec
+                # --------------- Next decision (lines 11-13) -----------------
+                # The engine-side drift verdict: computed once here, handed
+                # to the policy on the feedback (the deduped source of
+                # truth).
+                with span("decide"):
+                    drifted = self.allocator.observe_drift(acc_l, acc_v,
+                                                           clock)
+                    feedback = PhaseFeedback(
+                        acc_valid=acc_v, acc_label=acc_l, t=clock,
+                        phase_start=phase_start, retrain_time=retrain_time,
+                        label_time=label_time, drifted=drifted)
+                    next_raw = self.allocator.next_decision(feedback)
+                    next_dec = as_decision(next_raw)
+                    record = PhaseRecord(
+                        index=len(records), t=clock, acc_valid=acc_v,
+                        acc_label=acc_l, drift=next_dec.temporal.reset_buffer,
+                        retrain_time=retrain_time, label_time=label_time,
+                        decision=raw, next_decision=next_raw,
+                        phase_start=phase_start, t_tsa=plan.t_tsa,
+                        t_bsa=plan.t_bsa,
+                        spec_hits=pipe.hits - spec_seen[0],
+                        spec_misses=pipe.misses - spec_seen[1])
+                    records.append(record)
+                    for obs in observers:
+                        obs(record)
+                raw, dec = next_raw, next_dec
 
         score_until(duration, serving, None)
         acc_timeline = sink.timeline()

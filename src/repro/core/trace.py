@@ -1,12 +1,12 @@
-"""Per-program execution tracing for the dispatch layer (trace spine).
+"""The program's two execution records: the replay log and profiler spans.
 
-Every phase the engine executes flows through a
-:class:`~repro.core.dispatch.PhasePlan`: device programs are *dispatched*
-(``dispatch`` / ``dispatch_multi``) and bare virtual-time *charges* land on
-the role ledgers (``charge``).  A :class:`TraceRecorder` attached to the
-:class:`~repro.core.dispatch.KernelDispatcher` observes exactly that stream
-and records one :class:`TraceEvent` per device program (and per bare
-charge), in issue order, per phase:
+**Replay log (virtual clock).** Every phase the engine executes flows
+through a :class:`~repro.core.dispatch.PhasePlan`: device programs are
+*dispatched* (``dispatch`` / ``dispatch_multi``) and bare virtual-time
+*charges* land on the role ledgers (``charge``).  A :class:`TraceRecorder`
+attached to the :class:`~repro.core.dispatch.KernelDispatcher` observes
+exactly that stream and records one :class:`TraceEvent` per device program
+(and per bare charge), in issue order, per phase:
 
 * the **virtual-clock cost** the program charged (and to which role/lane),
 * the **host wall time** its issue took (``time.perf_counter`` around the
@@ -30,6 +30,25 @@ and estimator calibration) and round-trips to JSON losslessly
 (``save``/``load`` — floats survive bit-exactly via repr round-trip), so
 traces can be analyzed offline (``examples/continuous_learning_drive.py
 --trace``).
+
+**Profiler spans (the device trace's clock).** :func:`span` opens a
+``jax.profiler.TraceAnnotation`` named ``dacapo.<name>``. The engines, the
+dispatch layer, the data plane and the kernels open one at each layer
+boundary of the phase loop (per phase, window or program, never per
+frame), nested on the thread that runs them. A span is recorded only
+while a profiler session runs — then on the same clock as the device's
+operations, so a device idle gap can be put down to what the host was
+doing — and costs one object otherwise; there is no switch. The spans:
+
+* front door: ``phase``, tiled by ``plan``, ``retrain``, ``score``,
+  ``label``, ``barrier`` and ``decide``;
+* dispatch: ``issue.<label>`` around each program's issue (``valid``,
+  ``label``, ``acc_label``, ``score``), ``collect`` where a result is
+  materialized on the host;
+* data plane: ``data.frames`` with ``data.wait`` (the engine waiting on a
+  prefetched window), ``data.synthesize`` (on the prefetch worker's thread
+  or inline), ``buffer.update``, ``buffer.get``;
+* kernels: ``fit`` with ``fit.gather`` and ``fit.step``, ``quantize``.
 """
 from __future__ import annotations
 
@@ -37,9 +56,17 @@ import dataclasses
 import json
 from typing import Dict, List, Optional, Sequence
 
+import jax
+
 from repro.kernels.ops import kernel_stats
 
 TRACE_FORMAT = "dacapo-trace-v1"
+SPAN_PREFIX = "dacapo."
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A profiler span ``dacapo.<name>``, to be used as a context manager."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -29,6 +29,11 @@ a wrong frame).
 dispatch layer binds it into each :class:`~repro.core.dispatch.PhasePlan`
 (``plan.fetch``) so concurrent dispatch issues device programs against
 prefetched, host-ready windows.
+
+Profiler spans (core/trace.py): each request runs in ``dacapo.data.frames``,
+its wait for a window the worker is still synthesizing in
+``dacapo.data.wait``, and each window's synthesis — on the worker's thread
+or inline — in ``dacapo.data.synthesize``.
 """
 from __future__ import annotations
 
@@ -43,6 +48,13 @@ from repro.data.stream import DriftStream
 
 # A window key: one (rounded-time, segment-index) pair per frame.
 _WindowKey = Tuple[Tuple[str, int], ...]
+
+
+def _span(name: str):
+    """The program's profiler span ``dacapo.<name>``. Imported at the call:
+    ``repro.core`` imports this module while it initializes."""
+    from repro.core.trace import span
+    return span(name)
 
 
 def _window_key(stream: DriftStream, t0: float, t1: float,
@@ -177,8 +189,9 @@ class FramePipeline:
                     if batch.cancelled or self._stop.is_set():
                         break
                     try:
-                        x, y = self.stream.frames(w.t0, w.t1,
-                                                  max_frames=w.max_frames)
+                        with _span("data.synthesize"):
+                            x, y = self.stream.frames(
+                                w.t0, w.t1, max_frames=w.max_frames)
                     except Exception:
                         break  # surviving windows stay unset -> misses
                     w.x, w.y = x, y
@@ -237,8 +250,13 @@ class FramePipeline:
         from the speculation when the prediction reconciles. ``tag`` names
         the window's role in the phase layout (``"label"`` enables
         decision-aware pre-sizing on the next rotation)."""
+        with _span("data.frames"):
+            return self._frames(t0, t1, max_frames, tag)
+
+    def _frames(self, t0: float, t1: float, max_frames: int,
+                tag: Optional[str]) -> Tuple[np.ndarray, np.ndarray]:
         if not self.speculative:
-            return self.stream.frames(t0, t1, max_frames=max_frames)
+            return self._synthesize(t0, t1, max_frames)
         if self._phase_start is not None:
             self._trace.append((t0 - self._phase_start,
                                 t1 - self._phase_start, max_frames, tag))
@@ -248,12 +266,20 @@ class FramePipeline:
             if w is not None and not w.consumed:
                 # ready is set only after both arrays are stored, so it also
                 # guards the timeout path against a torn read.
-                if w.ready.wait(self.reconcile_timeout_s) and w.x is not None:
+                with _span("data.wait"):
+                    ready = w.ready.wait(self.reconcile_timeout_s)
+                if ready and w.x is not None:
                     w.consumed = True
                     self.stats.hits += 1
                     return w.x, w.y
         self.stats.misses += 1
-        return self.stream.frames(t0, t1, max_frames=max_frames)
+        return self._synthesize(t0, t1, max_frames)
+
+    def _synthesize(self, t0: float, t1: float, max_frames: int
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Inline synthesis of a window no prefetch served."""
+        with _span("data.synthesize"):
+            return self.stream.frames(t0, t1, max_frames=max_frames)
 
     # --------------------------------------------------------------- close
     def close(self) -> None:

@@ -258,3 +258,182 @@ def test_manager_parallel_trace_deterministic(pretrained):
             assert dataclasses.replace(ea, wall_s=0.0) \
                 == dataclasses.replace(eb, wall_s=0.0)
     assert {ph.shard for ph in tr_par.phases} == {0, 1, 2}
+
+
+# --------------------------------------------- profiler spans and h2d bytes
+# Every span of the phase loop that nests under ``dacapo.phase`` on the
+# engine's thread (``dacapo.data.synthesize`` runs on the prefetch worker).
+PHASE_SPANS = (
+    "plan", "retrain", "score", "label", "barrier", "decide",
+    "issue.valid", "issue.label", "issue.acc_label", "issue.score",
+    "collect", "data.frames", "data.wait", "buffer.update", "buffer.get",
+    "fit", "fit.gather", "fit.step", "quantize")
+
+
+def _profiled(fn):
+    """Run ``fn`` under an in-memory profiler session; returns its result
+    and the program's spans as ``(line, name, start_ns, end_ns)``, where
+    ``line`` is (plane name, line index): one line per host thread."""
+    import jax
+    from jax._src.lib import _profiler
+
+    from repro.core.trace import SPAN_PREFIX
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.enable_hlo_proto = False
+    session = _profiler.ProfilerSession(opts)
+    try:
+        out = fn()
+    finally:
+        data = session.stop_and_get_profile_data()
+    spans = [((plane.name, i), e.name[len(SPAN_PREFIX):], e.start_ns,
+              e.start_ns + e.duration_ns)
+             for plane in data.planes for i, line in enumerate(plane.lines)
+             for e in line.events if e.name.startswith(SPAN_PREFIX)]
+    return out, spans
+
+
+class _FrameCounter:
+    """Counts the host bytes of the frames and labels handed to the
+    kernels' entry points, read off each call's arrays."""
+
+    def __init__(self, session):
+        self.nbytes = 0
+        inf, lab, ret = session.inference, session.labeling, session.retrain
+        predict, label, step = inf.predict_async, lab.label_async, ret._step
+
+        def count(*arrays):
+            self.nbytes += sum(a.nbytes for a in arrays
+                               if isinstance(a, np.ndarray))
+
+        def predict_counted(params, x):
+            count(x)
+            return predict(params, x)
+
+        def label_counted(params, x, precision, microbatch=None):
+            count(x)
+            return label(params, x, precision, microbatch)
+
+        def step_counted(params, opt, x, y):
+            count(x, y)
+            return step(params, opt, x, y)
+
+        inf.predict_async, lab.label_async = predict_counted, label_counted
+        ret._step = step_counted
+
+
+def _engine_run(pretrained, engine, profile):
+    """A concurrent run of one camera on the reduced twins, with the MX
+    serving copies on: through the fleet engine or the session engine."""
+    hp, tp, sp = pretrained
+    stream = DriftStream(scenario("S1", 2), seed=5, img=24)
+    kw = dict(student=RESNET18, teacher=WIDERESNET50, hp=hp, apply_mx=True,
+              seed=0, eval_fps=0.5, dispatch="concurrent")
+    spec = FleetSpec(**kw) if engine == "fleet" else CLSystemSpec(**kw)
+    session = spec.build()
+    session.set_pretrained(tp, sp)
+    counter = _FrameCounter(session)
+
+    def go():
+        result = session.run(stream, duration=40.0)
+        return result.streams[0] if engine == "fleet" else result
+
+    if not profile:
+        return go(), None, session, counter
+    result, spans = _profiled(go)
+    return result, spans, session, counter
+
+
+@pytest.fixture(scope="module", params=["fleet", "session"])
+def profiled_runs(request, pretrained):
+    return (request.param,
+            _engine_run(pretrained, request.param, profile=True),
+            _engine_run(pretrained, request.param, profile=False))
+
+
+def _engine_line(spans):
+    lines = {line for line, name, _, _ in spans if name == "phase"}
+    assert len(lines) == 1, lines
+    return lines.pop()
+
+
+def test_span_helper_names_a_trace_annotation():
+    import jax
+
+    from repro.core.trace import span
+    with span("phase") as s:
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+
+
+def test_program_spans_nest_under_the_phase_on_one_line(profiled_runs):
+    """Every span of the phase loop appears inside a ``dacapo.phase`` span
+    on the engine's line."""
+    _, (result, spans, _, _), _ = profiled_runs
+    assert sum(r.spec_hits for r in result.records) > 0
+    engine = _engine_line(spans)
+    phases = [(s, e) for line, name, s, e in spans
+              if line == engine and name == "phase"]
+    # A phase that reaches the duration mid-phase leaves no record.
+    assert len(result.records) >= 3
+    assert len(phases) - len(result.records) in (0, 1)
+    nested = {name for line, name, s, e in spans if line == engine
+              and any(p0 <= s and e <= p1 for p0, p1 in phases)}
+    assert set(PHASE_SPANS) <= nested, set(PHASE_SPANS) - nested
+
+
+def test_frame_synthesis_runs_on_the_prefetch_worker_line(profiled_runs):
+    _, (_, spans, _, _), _ = profiled_runs
+    engine = _engine_line(spans)
+    worker = {line for line, name, _, _ in spans
+              if name == "data.synthesize" and line != engine}
+    assert len(worker) == 1
+    assert not {name for line, name, _, _ in spans
+                if line in worker} - {"data.synthesize"}
+
+
+def test_profiled_run_is_bit_identical_to_an_unprofiled_one(profiled_runs):
+    _, (r_on, _, s_on, _), (r_off, _, s_off, _) = profiled_runs
+    assert r_on.avg_accuracy == r_off.avg_accuracy
+    assert r_on.accuracy_timeline == r_off.accuracy_timeline
+    assert r_on.phase_log == r_off.phase_log
+    for k_on, k_off in zip(s_on.kernels, s_off.kernels):
+        assert k_on.n_apply_calls == k_off.n_apply_calls
+        assert k_on.h2d_bytes == k_off.h2d_bytes
+
+
+def test_h2d_bytes_count_the_frames_and_labels_handed_over(profiled_runs):
+    """Frames served, labeled and trained on, and the SGD labels: the
+    kernels' counters against the arrays the run handed them."""
+    _, (_, _, session, counter), _ = profiled_runs
+    frame = 24 * 24 * 3 * np.dtype(np.float32).itemsize
+    inf, lab, ret = session.kernels
+    assert inf.h2d_bytes > 0 and lab.h2d_bytes > 0 and ret.h2d_bytes > 0
+    assert inf.h2d_bytes % frame == 0 and lab.h2d_bytes % frame == 0
+    # Each SGD batch brings its labels as int32.
+    n_sgd = ret.n_apply_calls * session.hp.sgd_batch
+    assert ret.h2d_bytes == n_sgd * (frame + 4)
+    assert sum(k.h2d_bytes for k in session.kernels) == counter.nbytes
+
+
+def test_h2d_bytes_count_padded_fleet_batches_not_device_arrays():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.kernel import InferenceKernel
+
+    vc = RESNET18.reduced()
+    model = make_vision_model(vc)
+    kernel = InferenceKernel(model, RESNET18, DaCapoEstimator(),
+                             apply_mx=False)
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    frames = [rng.normal(size=(n, 24, 24, 3)).astype(np.float32)
+              for n in (3, 5)]
+    kernel.predict_fleet_async([params, params], frames)
+    assert kernel.h2d_bytes == 2 * frames[1].nbytes  # padded to 5 rows
+    kernel.predict_async(params, jnp.asarray(frames[0]))
+    assert kernel.h2d_bytes == 2 * frames[1].nbytes
+    kernel.predict_batched(params, frames)
+    assert kernel.h2d_bytes == 3 * frames[1].nbytes + frames[0].nbytes
