@@ -47,7 +47,8 @@ doing — and costs one object otherwise; there is no switch. The spans:
   materialized on the host;
 * data plane: ``data.frames`` with ``data.wait`` (the engine waiting on a
   prefetched window), ``data.synthesize`` (on the prefetch worker's thread
-  or inline), ``buffer.update``, ``buffer.get``;
+  or inline), ``data.render`` (a chunk of a window on the render pool's
+  thread), ``buffer.update``, ``buffer.get``;
 * kernels: ``fit`` with ``fit.gather`` and ``fit.step``, ``quantize``.
 """
 from __future__ import annotations

@@ -33,7 +33,8 @@ prefetched, host-ready windows.
 Profiler spans (core/trace.py): each request runs in ``dacapo.data.frames``,
 its wait for a window the worker is still synthesizing in
 ``dacapo.data.wait``, and each window's synthesis — on the worker's thread
-or inline — in ``dacapo.data.synthesize``.
+or inline — in ``dacapo.data.synthesize``, inside which a large window's
+chunks render on the stream's shared pool, each in ``dacapo.data.render``.
 """
 from __future__ import annotations
 
@@ -44,17 +45,10 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.data.stream import DriftStream
+from repro.data.stream import DriftStream, _span
 
 # A window key: one (rounded-time, segment-index) pair per frame.
 _WindowKey = Tuple[Tuple[str, int], ...]
-
-
-def _span(name: str):
-    """The program's profiler span ``dacapo.<name>``. Imported at the call:
-    ``repro.core`` imports this module while it initializes."""
-    from repro.core.trace import span
-    return span(name)
 
 
 def _window_key(stream: DriftStream, t0: float, t1: float,

@@ -12,20 +12,87 @@ Scenario tables S1-S6 / ES1-ES2 mirror Table II: 20-minute streams at 30 FPS
 built from 60-second segments; each segment flips one (regular) or all four
 (extreme) attributes. Frames are generated deterministically from (scenario
 seed, time) so every system variant scores the identical stream.
+
+A window of frames is rendered into one preallocated array. Almost all of a
+frame's cost is numpy work that releases the interpreter lock (its noise
+draw and image arithmetic), so a large window is split into contiguous
+chunks rendered on one process-wide pool of host threads
+(:func:`_render_pool`), each in a ``dacapo.data.render`` profiler span; a
+small one renders on the calling thread. Every frame is the same either way.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import hashlib
+import os
 import queue
 import threading
-from typing import Iterator, List, Sequence, Tuple
+from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 N_CLASSES = 8
 IMG = 32
 TRAFFIC_CLASSES = (0, 1, 2, 3, 4)
+
+# Render pool sizing (PERF.md section 5 has the curve it comes from). Cores
+# kept free of it: the engine thread and the JAX runtime's.
+_RESERVED_CORES = 2
+# Threads past which a window renders no faster on a one-chip TPU v5e host.
+_MAX_RENDER_THREADS = 8
+# Least pixels (frames x img x img x 3) one chunk renders: below it the
+# pool's hand-off costs more than the chunk's share of the work saves.
+_MIN_CHUNK_PIXELS = 1 << 17
+# Least pixels of one frame for it to fan out: a smaller frame's cost is
+# mostly its hash, RNG set-up and label draw, which hold the lock.
+_MIN_FRAME_PIXELS = 128 * 128 * 3
+
+_pool: Optional[concurrent.futures.ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _span(name: str):
+    """The program's profiler span ``dacapo.<name>``. Imported at the call:
+    ``repro.core`` imports this package while it initializes."""
+    from repro.core.trace import span
+    return span(name)
+
+
+def _render_threads() -> int:
+    """Threads of the render pool: the cores this process may use, less
+    those kept for the engine and the runtime, at most
+    ``_MAX_RENDER_THREADS``."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # not on Linux
+        cores = os.cpu_count() or 1
+    return max(1, min(_MAX_RENDER_THREADS, cores - _RESERVED_CORES))
+
+
+def _render_pool() -> concurrent.futures.ThreadPoolExecutor:
+    """The process's one render pool, made at its first use and shared by
+    every stream (a fleet's prefetch workers included). Its tasks render
+    frames and never wait on the pool, so callers on any number of threads
+    cannot deadlock it."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = concurrent.futures.ThreadPoolExecutor(
+                _render_threads(), thread_name_prefix="dacapo-render")
+        return _pool
+
+
+def _chunks(n: int, frame_pixels: int, threads: int
+            ) -> List[Tuple[int, int]]:
+    """Contiguous ``[lo, hi)`` row ranges to render ``n`` frames of
+    ``frame_pixels`` each in: one range (on the calling thread) unless the
+    window is large enough for several chunks of ``_MIN_CHUNK_PIXELS``,
+    then up to two per pool thread."""
+    k = 1
+    if threads > 1 and frame_pixels >= _MIN_FRAME_PIXELS:
+        k = max(1, min(n, 2 * threads, n * frame_pixels // _MIN_CHUNK_PIXELS))
+    return [(i * n // k, (i + 1) * n // k) for i in range(k)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,6 +174,10 @@ class DriftStream:
         self._city_tex = rng.normal(size=(img, img, 3)) * 0.6
         gradient = np.linspace(-1, 1, img)[:, None, None]
         self._highway_tex = np.broadcast_to(gradient, (img, img, 3)) * 0.6
+        # Frames rendered on the pool and on the calling thread.
+        self.frames_pooled = 0
+        self.frames_serial = 0
+        self._count_lock = threading.Lock()
 
     @property
     def duration(self) -> float:
@@ -144,15 +215,45 @@ class DriftStream:
     def frames(self, t0: float, t1: float,
                max_frames: int = 0) -> Tuple[np.ndarray, np.ndarray]:
         """Frames in [t0, t1); optionally uniformly subsampled."""
-        times = self.frame_times(t0, t1, max_frames)
-        xs, ys = [], []
-        for t in times:
-            x, y = self._frame(float(t))
-            xs.append(x)
-            ys.append(y)
-        return np.stack(xs), np.asarray(ys, np.int32)
+        return self._render(self.frame_times(t0, t1, max_frames))
 
-    def _frame(self, t: float) -> Tuple[np.ndarray, int]:
+    def _render(self, times: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """The frames at ``times`` (float32, stacked) and their labels,
+        rendered into one preallocated array: in chunks on the render pool
+        when the window is large enough (:func:`_chunks`), else here."""
+        n = len(times)
+        xs = np.empty((n, self.img, self.img, 3), np.float32)
+        ys = np.empty(n, np.int32)
+
+        def render(lo: int, hi: int) -> None:
+            for i in range(lo, hi):
+                ys[i] = self._frame(float(times[i]), out=xs[i])[1]
+
+        def render_chunk(lo: int, hi: int) -> None:
+            with _span("data.render"):
+                render(lo, hi)
+
+        chunks = _chunks(n, self.img * self.img * 3, _render_threads())
+        if len(chunks) == 1:
+            render(0, n)
+        else:
+            pool = _render_pool()
+            futures = [pool.submit(render_chunk, lo, hi) for lo, hi in chunks]
+            concurrent.futures.wait(futures)
+            for f in futures:
+                f.result()
+        with self._count_lock:
+            if len(chunks) == 1:
+                self.frames_serial += n
+            else:
+                self.frames_pooled += n
+        return xs, ys
+
+    def _frame(self, t: float, out: Optional[np.ndarray] = None
+               ) -> Tuple[np.ndarray, int]:
+        """The frame at time ``t`` (float32) and its label: the one
+        definition of a frame. With ``out``, the pixels are stored there,
+        rounded to float32 exactly as ``astype`` rounds them."""
         seg = self.segment_at(t)
         # Deterministic per-frame RNG.
         h = hashlib.blake2b(f"{self.seed}:{t:.4f}".encode(),
@@ -177,7 +278,10 @@ class DriftStream:
         elif seg.weather == "snowy":
             flakes = (rng.random(x.shape[:2]) < 0.10)[..., None] * 2.0
             x = x * 0.9 + flakes
-        return x.astype(np.float32), y
+        if out is None:
+            return x.astype(np.float32), y
+        out[...] = x
+        return out, y
 
     def windows(self, t0: float, t1: float, window_s: float,
                 max_frames: int = 0,
@@ -201,21 +305,15 @@ class DriftStream:
         must be the world the CL system is later scored on (the sampler
         only randomizes the timestamps)."""
         segs = list(segments) if segments is not None else self.segments
-        xs, ys = [], []
         stream = DriftStream(segs, fps=self.fps, seed=self.seed,
                              img=self.img, n_classes=self.n_classes)
-        times = rng.uniform(0, stream.duration, size=n)
-        for t in times:
-            x, y = stream._frame(float(t))
-            xs.append(x)
-            ys.append(y)
-        return np.stack(xs), np.asarray(ys, np.int32)
+        return stream._render(rng.uniform(0, stream.duration, size=n))
 
 
 class PrefetchingWindowIterator:
     """Frame windows generated ahead of consumption on a background thread.
 
-    Host-side frame synthesis is a serial numpy loop; when the consumer
+    Frame synthesis is numpy work on the host; when the consumer
     dispatches async device work per window (core/dispatch.py), generating
     the *next* window on a worker thread overlaps CPU frame slicing with
     device execution instead of serializing the dispatch stream. Windows are

@@ -437,3 +437,29 @@ def test_h2d_bytes_count_padded_fleet_batches_not_device_arrays():
     assert kernel.h2d_bytes == 2 * frames[1].nbytes
     kernel.predict_batched(params, frames)
     assert kernel.h2d_bytes == 3 * frames[1].nbytes + frames[0].nbytes
+
+
+def test_pooled_window_renders_in_chunks_inside_its_synthesis(monkeypatch):
+    """A window above the fan-out threshold is one ``data.synthesize`` span
+    on the asking thread and ``data.render`` spans on the render pool's
+    threads, each inside it in time."""
+    import repro.data.stream as stream_mod
+    from repro.data.pipeline import FramePipeline
+    from repro.data.stream import DriftStream, scenario
+
+    monkeypatch.setattr(stream_mod, "_render_threads", lambda: 4)
+    monkeypatch.setattr(stream_mod, "_pool", None)
+    pipe = FramePipeline(DriftStream(scenario("S1", 1), seed=5, img=224),
+                         speculative=False)
+    try:
+        (x, _), spans = _profiled(lambda: pipe.frames(0.0, 0.5))
+    finally:
+        stream_mod._pool.shutdown(wait=True)
+    assert len(x) == 15 and pipe.stream.frames_pooled == 15
+    synth = [(line, s, e) for line, name, s, e in spans
+             if name == "data.synthesize"]
+    assert len(synth) == 1
+    line, s0, e0 = synth[0]
+    render = [(ln, s, e) for ln, name, s, e in spans if name == "data.render"]
+    assert len(render) == 8  # two chunks for each of the four threads
+    assert all(ln != line and s0 <= s and e <= e0 for ln, s, e in render)
