@@ -5,13 +5,16 @@ Three kinds of numbers, each with a limit in ``configs/<config>.json``:
 
 * training (the first three SGD steps of lane 0, which set-up drives
   through the window's own call): ``sgd_loss``, the largest relative gap of
-  a step's loss; ``sgd_grad``, the worst leaf's gap between the norms of the
-  first gradient (the momentum after one step) of program and reference;
-  ``sgd_update``, the worst leaf's gap between the norms of the parameters'
-  change over the three steps. A leaf's gap is measured against the larger
-  of the reference's norm of that leaf and of the median leaf. Leaves whose
-  first gradient in the reference is under a thousandth of the median
-  leaf's move by round-off alone and are left out of ``sgd_update``.
+  a step's loss; ``sgd_grad``, the worst leaf's norm of the difference
+  between the first gradients (the momentum after one step) of program and
+  reference; ``sgd_update``, the worst leaf's norm of the difference
+  between the parameters' changes over the three steps. Vectors, not their
+  norms, are compared: a gradient from half the batch has about the norm of
+  the whole batch's but points elsewhere. A leaf's gap is measured against
+  the larger of the reference's norm of that leaf and of the median leaf.
+  Leaves whose first gradient in the reference is under a thousandth of the
+  median leaf's move by round-off alone and are left out of
+  ``sgd_update``.
 * labels: ``label_gap``, the widest gap by which the reference teacher's
   logit of a label the window produced lies below its best logit, over a
   seeded sample of the labeled frames.
@@ -27,7 +30,7 @@ copies at the precisions the configuration states.
 from __future__ import annotations
 
 from collections import defaultdict
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,27 +41,32 @@ import reference
 MOVING = 1e-3  # a leaf moves when its first gradient is >= this x median
 
 
-def _norms(tree) -> List[float]:
-    return [float(np.linalg.norm(np.asarray(a, np.float64)))
-            for a in jax.tree_util.tree_leaves(tree)]
+def leaf_gaps(got, want, base=None) -> Tuple[List[float], List[float]]:
+    """Per leaf, in float64: the norm of ``got - want``, and the norm of
+    ``want - base`` (of ``want`` where ``base`` is None)."""
+    gaps, norms = [], []
+    want_leaves = jax.tree_util.tree_leaves(want)
+    base_leaves = (jax.tree_util.tree_leaves(base) if base is not None
+                   else [0.0] * len(want_leaves))
+    for g, w, b in zip(jax.tree_util.tree_leaves(got), want_leaves,
+                       base_leaves):
+        w = np.asarray(w, np.float64)
+        gaps.append(float(np.linalg.norm(np.asarray(g, np.float64) - w)))
+        norms.append(float(np.linalg.norm(w - np.asarray(b, np.float64))))
+    return gaps, norms
 
 
-def _deltas(tree, base) -> List[float]:
-    return [float(np.linalg.norm(np.asarray(a, np.float64)
-                                 - np.asarray(b, np.float64)))
-            for a, b in zip(jax.tree_util.tree_leaves(tree),
-                            jax.tree_util.tree_leaves(base))]
-
-
-def _worst(got: Sequence[float], want: Sequence[float],
-           keep: Optional[Sequence[bool]] = None) -> float:
-    keep = [True] * len(want) if keep is None else keep
-    base = [w for w, k in zip(want, keep) if k]
+def leaf_ratios(gaps: Sequence[float], norms: Sequence[float],
+                keep: Optional[Sequence[bool]] = None) -> List[float]:
+    """Each kept leaf's gap over the larger of its norm and the median kept
+    leaf's norm."""
+    keep = [True] * len(norms) if keep is None else keep
+    base = [n for n, k in zip(norms, keep) if k]
     if not base:
-        return float("inf")
+        return []
     med = float(np.median(base))
-    return max(abs(g - w) / max(w, med, 1e-30)
-               for g, w, k in zip(got, want, keep) if k)
+    return [d / max(n, med, 1e-30)
+            for d, n, k in zip(gaps, norms, keep) if k]
 
 
 class Truth:
@@ -130,25 +138,34 @@ def answer_gap(ref_logits: Sequence[np.ndarray],
     return float(np.concatenate(gaps).max()) if gaps else float("inf")
 
 
+def training_ratios(truth_first: Dict[str, Any], s_params,
+                    got: Dict[str, Any]) -> Dict[str, List[float]]:
+    """Per leaf, the gaps of a side's first gradient (``mom1``) and of its
+    change over three steps (``params3``, moving leaves only) against the
+    reference's, as :func:`leaf_ratios` gives them."""
+    grad_gap, grad_ref = leaf_gaps(got["mom1"], truth_first["grad1"])
+    med = float(np.median(grad_ref))
+    moving = [g >= MOVING * med for g in grad_ref]
+    upd_gap, upd_ref = leaf_gaps(got["params3"], truth_first["params3"],
+                                 s_params)
+    return {"sgd_grad": leaf_ratios(grad_gap, grad_ref),
+            "sgd_update": leaf_ratios(upd_gap, upd_ref, moving)}
+
+
 def training_gaps(truth_first: Dict[str, Any], s_params,
                   got: Dict[str, Any]) -> Dict[str, float]:
     """Gaps of a side's first three steps (``loss``, ``mom1``,
-    ``params3``) against the reference's."""
+    ``params3``) against the reference's: the worst step's loss, and the
+    worst leaf of :func:`training_ratios`."""
     want_loss = truth_first["loss"]
     if len(got.get("loss", [])) < 3 or len(want_loss) < 3:
         inf = float("inf")
         return {"sgd_loss": inf, "sgd_grad": inf, "sgd_update": inf}
-    grad_ref = _norms(truth_first["grad1"])
-    med = float(np.median(grad_ref))
-    moving = [g >= MOVING * med for g in grad_ref]
-    return {
-        "sgd_loss": max(abs(g - w) / abs(w)
-                        for g, w in zip(got["loss"], want_loss)),
-        "sgd_grad": _worst(_norms(got["mom1"]), grad_ref),
-        "sgd_update": _worst(_deltas(got["params3"], s_params),
-                             _deltas(truth_first["params3"], s_params),
-                             moving),
-    }
+    out = {"sgd_loss": max(abs(g - w) / abs(w)
+                           for g, w in zip(got["loss"], want_loss))}
+    for k, ratios in training_ratios(truth_first, s_params, got).items():
+        out[k] = max(ratios, default=float("inf"))
+    return out
 
 
 def correct(readings: Dict[str, float], limits: Dict[str, float]) -> bool:

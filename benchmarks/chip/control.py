@@ -19,7 +19,10 @@ other sides put in the program's place, on the same recorded inputs:
 Each side is judged as the harness judges the program: its numbers, with
 the program's own in place of those the side does not change, against the
 configuration's limits. One JSON line per seed goes to standard output (and
-to ``--out``): every side's readings and its ``correct``.
+to ``--out``): every side's readings and its ``correct``, and under
+``median_leaf`` the median leaf's gaps of ``sgd_grad`` and ``sgd_update``
+for the program and each side that trains (not compared; they show whether
+the worst leaf is noise of one small leaf).
 """
 import time
 
@@ -35,7 +38,8 @@ sys.path[:0] = [HERE, os.path.join(HERE, "..", "..", "src")]
 
 
 def side_readings(truth, ctrl, s_params, rec, config):
-    """Readings of the control and of each planted fault."""
+    """Readings of the control and of each planted fault, and the median
+    leaf's training gaps of each side that trains and of the program."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -49,15 +53,16 @@ def side_readings(truth, ctrl, s_params, rec, config):
     n_cls = config["student"]["num_classes"]
 
     out = {}
-    c_first = ctrl.first_steps()
+    trained = {"program": rec["first"]}
+    c_first = trained["control"] = ctrl.first_steps()
     out["control"] = check.training_gaps(first, s_params, c_first)
     out["control"]["label_gap"] = check.answer_gap(
         lab_ref, [z.argmax(-1) for z in ctrl.label_logits()])
     out["control"]["serve_gap"] = check.answer_gap(
         srv_ref, [z.argmax(-1) for z in ctrl.serve_logits()])
 
-    out["half_batch"] = check.training_gaps(
-        first, s_params, truth.first_steps(half_batch=True))
+    half = trained["half_batch"] = truth.first_steps(half_batch=True)
+    out["half_batch"] = check.training_gaps(first, s_params, half)
 
     # A step that returns its state unchanged: every loss is taken at the
     # starting weights, the momentum stays zero, the weights do not move.
@@ -69,6 +74,10 @@ def side_readings(truth, ctrl, s_params, rec, config):
         still["loss"].append(float(truth.student.step(
             truth.lr, s_params, zero, x, y)[2]))
     out["state_unchanged"] = check.training_gaps(first, s_params, still)
+    out["median_leaf"] = {
+        side: {k: float(np.median(r)) for k, r in
+               check.training_ratios(first, s_params, got).items()}
+        for side, got in trained.items()}
 
     out["answer_altered"] = {
         "label_gap": check.answer_gap(lab_ref, [(a + 1) % n_cls
@@ -110,6 +119,8 @@ def main(argv=None):
         sides = side_readings(truth, ctrl, s_params, rec, cell.config)
         line = {"workload": cell.name, "seed": seed,
                 "limits": out["limits"],
+                "median_leaf": sides.pop("median_leaf"),
+                "memory_peak_bytes": out["memory_peak"],
                 "program": {**out["readings"], "correct": out["correct"]}}
         for side, got in sides.items():
             got = {**out["readings"], **got}

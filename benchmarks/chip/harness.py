@@ -158,7 +158,9 @@ class TimedPipeline(FramePipeline):
 class Recorder:
     """Watches the kernels' calls: every SGD batch (for the reference to
     replay), the first three steps' outputs, and a seeded sample of the rows
-    each serving and labeling call in the window answered."""
+    each serving and labeling call in the window answered. A fleet serving
+    call (one program for several lanes) counts as one call per lane; one
+    with a single lane goes through ``predict_async`` and counts there."""
 
     def __init__(self, session, run, seed: int, spans: Spans):
         self.run = run
@@ -175,6 +177,7 @@ class Recorder:
         inf, lab, ret = session.inference, session.labeling, session.retrain
         fit, step = ret.fit, ret._step
         predict, label = inf.predict_async, lab.label_async
+        predict_fleet = inf.predict_fleet_async
 
         def fit_wrapped(params, opt, *args, **kwargs):
             self._lane = self._lane_of(params, "params")
@@ -197,11 +200,15 @@ class Recorder:
             with self.spans("serve"):
                 out = predict(params, x)
             if self.in_window:
-                lane = self._lane_of(params, "serving")
-                rows = self._rows(len(x))
-                self.served.append((lane, len(self.steps[lane]),
-                                    np.asarray(x[rows]), out, rows))
-                self.rows_served += len(x)
+                self._serve(params, x, out)
+            return out
+
+        def predict_fleet_wrapped(params_list, windows):
+            with self.spans("serve"):
+                out = predict_fleet(params_list, windows)
+            if self.in_window and len(windows) > 1:
+                for params, x, preds in zip(params_list, windows, out):
+                    self._serve(params, x, preds)
             return out
 
         def label_wrapped(params, x, precision, microbatch=None):
@@ -215,6 +222,16 @@ class Recorder:
 
         ret.fit, ret._step = fit_wrapped, step_wrapped
         inf.predict_async, lab.label_async = predict_wrapped, label_wrapped
+        inf.predict_fleet_async = predict_fleet_wrapped
+
+    def _serve(self, params, x, preds) -> None:
+        """Keep a seeded sample of the rows one lane's serving answered,
+        with the lane's version (its SGD steps so far)."""
+        lane = self._lane_of(params, "serving")
+        rows = self._rows(len(x))
+        self.served.append((lane, len(self.steps[lane]),
+                            np.asarray(x[rows]), preds, rows))
+        self.rows_served += len(x)
 
     def _lane_of(self, tree, attr: str) -> int:
         for lane in self.run.lanes:
@@ -382,6 +399,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         base = dict(
             clock=run.clock, compiles=meter.compiles,
             calls={k.name: k.n_apply_calls for k in session.kernels},
+            h2d_bytes=sum(k.h2d_bytes for k in session.kernels),
             fetch_s=sum(p.fetch_s for p in pipes),
             label_frames=sum(p.label_frames for p in pipes))
         rec.in_window = True
@@ -425,6 +443,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             phases=len(records) // max(1, len(run.lanes)),
             drift_phases=sum(r.drift for r in records),
             calls=calls, sgd_steps=calls["retraining"],
+            h2d_bytes=sum(k.h2d_bytes for k in session.kernels)
+            - base["h2d_bytes"],
             rows_served=rec.rows_served, rows_labeled=rec.rows_labeled,
             label_frames=sum(p.label_frames for p in pipes)
             - base["label_frames"],
@@ -476,7 +496,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     correct = check.correct(readings, limits)
     log(f"check: {time.perf_counter() - t_check!r} s, "
         f"{len(recorded['served'])} serving and {len(recorded['labeled'])} "
-        f"labeling calls sampled")
+        f"labeling calls sampled; lanes served "
+        f"{sorted({lane for lane, *_ in recorded['served']})}")
 
     return dict(ctx=ctx, correct=correct, readings=readings, limits=limits,
                 memory_peak=memory_peak, device=device,
@@ -519,7 +540,7 @@ def trace_summary(ctx: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     idle = sorted(idle, key=lambda g: -g[0])[:10]
     return dict(busy_s=float(busy), window_s=(hi - lo) / 1e9,
                 device_ops=[[n, s] for n, s in top],
-                idle_gaps=[[trace_reduce.host_activity(g, tr.spans), d / 1e9]
+                idle_gaps=[[trace_reduce.host_activity(g, tr), d / 1e9]
                            for d, g in idle])
 
 

@@ -1,34 +1,29 @@
-"""The program's own profiler spans and host-to-device counter, read beside
-a run of a cell (not part of a benchmark run).
+"""The program's own profiler spans, read beside a run of a cell (not part
+of a benchmark run).
 
     python3 benchmarks/chip/program_spans.py --workload <cell> --seed <n> \
         --seconds <s> --trace <0|1> [--out <file.json>]
 
 The program opens ``dacapo.*`` spans at each layer boundary of its phase
 loop (``src/repro/core/trace.py``), and each kernel counts the bytes of the
-host arrays it hands to the device (``h2d_bytes``). ``run.py`` reports
-neither: ``trace_reduce.load`` keeps the benchmark's ``bench.*`` spans only,
-and the harness's context has no ``h2d_bytes``. This module reads both:
+host arrays it hands to the device (``h2d_bytes``). ``trace_reduce.load``
+keeps the spans with their line, ``trace_reduce.host_activity`` names an
+idle gap by their self time, and the harness puts the window's
+``h2d_bytes`` in its context. This module holds:
 
-* :func:`load`: the trace as ``trace_reduce.load`` makes it, plus the
-  program's spans with their plane and line (:class:`Trace`); the engine
-  line is the one that holds the ``dacapo.phase`` spans;
-* :func:`host_activity`: an idle gap that program spans cover on the engine
-  line is named by self time (each instant goes to the innermost program
-  span open then, ``dacapo.phase`` itself excluded; the gap takes the name
-  that holds most of it); any other gap is named by
-  ``trace_reduce.host_activity``, unchanged;
 * :data:`METRICS`: five per-layer metrics, each ``read(ctx)`` on the
-  harness's context with this module's trace and ``h2d_bytes`` (``None``
-  where there is nothing to read);
+  harness's context (``None`` where there is nothing to read); each has a
+  reader of its own in ``metrics/`` that calls it;
 * :func:`run`: one run of a cell as ``run.py`` makes it, which also prints
-  these, the idle gaps named by the rule above, the wall time of each
-  phase of the window (and, traced, of each ``dacapo.phase`` span), and
-  the interpreter's garbage-collection pauses (:func:`gc_spans`), which
-  stop every thread and so no program span can name.
+  the idle gaps with their start, the engine line's time by span, the wall
+  time of each phase of the window (and, traced, of each ``dacapo.phase``
+  span), and the interpreter's garbage-collection pauses
+  (:func:`gc_spans`), which stop every thread and so no program span can
+  name.
 
 The last line of standard output is one JSON object, also written to
-``--out``.
+``--out``. Its ``metrics`` are ``run.py``'s readers on the run's context,
+and ``program_metrics`` the five of :data:`METRICS` on the same context.
 """
 import time
 
@@ -36,7 +31,6 @@ T_START = time.perf_counter()  # set-up is timed from the process's start
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -49,99 +43,10 @@ sys.path[:0] = [HERE, os.path.join(HERE, "..", "..", "src")]
 
 import trace_reduce  # noqa: E402
 
-PROGRAM_PREFIX = "dacapo."
-PHASE = "dacapo.phase"
-Line = Tuple[str, int]  # (host plane, index of the line in it): one thread
-ProgramSpan = Tuple[str, float, float, Line]  # (name, start_ns, end_ns, line)
-
-
-@dataclasses.dataclass
-class Trace(trace_reduce.Trace):
-    """``trace_reduce.Trace`` with the program's spans."""
-
-    program_spans: List[ProgramSpan] = dataclasses.field(
-        default_factory=list)
-
-
-def load(data) -> Trace:
-    """The metrics' view of a ``jax.profiler.ProfileData``, with the
-    program's ``dacapo.*`` spans of every host line."""
-    base = trace_reduce.load(data)
-    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, (plane.name, i))
-             for plane in data.planes if plane.name.startswith("/host:")
-             for i, line in enumerate(plane.lines)
-             for e in line.events if e.name.startswith(PROGRAM_PREFIX)]
-    return Trace(base.ops, base.modules, base.spans, spans)
-
-
-def engine_line(tr) -> Optional[Line]:
-    """The line that holds the ``dacapo.phase`` spans (the most of them)."""
-    lines = Counter(line for name, _, _, line
-                    in getattr(tr, "program_spans", ()) if name == PHASE)
-    return lines.most_common(1)[0][0] if lines else None
-
-
-def on_line(tr, line: Line) -> List[Tuple[str, float, float]]:
-    return [(n, s, e) for n, s, e, ln in tr.program_spans if ln == line]
-
-
-def self_time(spans) -> List[Tuple[float, float, str]]:
-    """Disjoint, sorted pieces ``(start, end, name)``: each instant covered
-    by one thread's nested ``spans`` goes to the innermost span open then."""
-    out: List[Tuple[float, float, str]] = []
-    stack: List[Tuple[str, float]] = []  # (name, end) of the open spans
-    t = 0.0
-    for name, s, e in sorted(spans, key=lambda sp: (sp[1], -sp[2])):
-        while stack and stack[-1][1] <= s:
-            top, end = stack.pop()
-            if end > t:
-                out.append((t, end, top))
-                t = end
-        if stack and s > t:
-            out.append((t, s, stack[-1][0]))
-        stack.append((name, e))
-        t = s
-    while stack:
-        top, end = stack.pop()
-        if end > t:
-            out.append((t, end, top))
-            t = end
-    return out
-
-
-def overlap_by_name(pieces, intervals) -> Dict[str, float]:
-    """Nanoseconds of each name's ``pieces`` (as :func:`self_time` gives
-    them) inside the union of ``intervals``."""
-    ivs = trace_reduce.union(intervals)
-    out: Dict[str, float] = defaultdict(float)
-    j = 0
-    for s, e, name in pieces:
-        while j < len(ivs) and ivs[j][1] <= s:
-            j += 1
-        k = j
-        while k < len(ivs) and ivs[k][0] < e:
-            out[name] += min(e, ivs[k][1]) - max(s, ivs[k][0])
-            k += 1
-    return dict(out)
-
-
-def host_activity(gap, tr) -> str:
-    """The name of an idle gap: the program span holding most of it by self
-    time on the engine line (``dacapo.phase`` excluded), else the
-    benchmark's rule (``trace_reduce.host_activity``)."""
-    line = engine_line(tr)
-    if line is not None:
-        held = overlap_by_name(self_time(on_line(tr, line)), [gap])
-        held.pop(PHASE, None)
-        if held:
-            return max(sorted(held), key=held.get)
-    return trace_reduce.host_activity(gap, tr.spans)
-
-
 def idle_gaps(ctx: Dict[str, Any], n: int = 10) -> List[list]:
     """The ``n`` longest idle gaps of the traced window, as
     ``harness.trace_summary`` picks them: ``[name, seconds, start]``, named
-    by :func:`host_activity`, ``start`` in seconds from the window's."""
+    by ``trace_reduce.host_activity``, ``start`` in seconds from the window's."""
     tr = ctx["trace"]
     win = trace_reduce.window(tr) if tr else None
     if win is None:
@@ -149,14 +54,15 @@ def idle_gaps(ctx: Dict[str, Any], n: int = 10) -> List[list]:
     found = [(g[1] - g[0], g)
              for iv in trace_reduce.chip_ops(tr, ctx["chips"])
              for g in trace_reduce.gaps(iv, *win)]
-    return [[host_activity(g, tr), d / 1e9, (g[0] - win[0]) / 1e9]
+    return [[trace_reduce.host_activity(g, tr), d / 1e9,
+             (g[0] - win[0]) / 1e9]
             for d, g in sorted(found, key=lambda f: -f[0])[:n]]
 
 
 def _traced(tr):
     """The traced window and the engine line, or ``None``."""
     win = trace_reduce.window(tr) if tr else None
-    line = engine_line(tr) if win else None
+    line = trace_reduce.engine_line(tr) if win else None
     return None if line is None else (win, line)
 
 
@@ -168,17 +74,19 @@ def engine_self_s(tr) -> Dict[str, float]:
     if got is None:
         return {}
     win, line = got
-    held = overlap_by_name(self_time(on_line(tr, line)), [win])
+    held = trace_reduce.overlap_by_name(
+        trace_reduce.self_time(trace_reduce.on_line(tr, line)), [win])
     return {k: v / 1e9 for k, v in sorted(held.items(), key=lambda kv: -kv[1])}
 
 
 # ---------------------------------------------------------------- metrics
 
 
-def _seconds_in(tr, win, name: str, line: Optional[Line]) -> float:
+def _seconds_in(tr, win, name: str,
+                line: Optional[trace_reduce.Line]) -> float:
     """Seconds of the window inside spans ``name``: on ``line``, or summed
     over each line where ``line`` is None."""
-    per_line: Dict[Line, list] = defaultdict(list)
+    per_line: Dict[trace_reduce.Line, list] = defaultdict(list)
     for n, s, e, ln in tr.program_spans:
         if n == name and (line is None or ln == line):
             per_line[ln].append((s, e))
@@ -229,13 +137,13 @@ def device_idle_unattributed_share(ctx):
     chips = trace_reduce.chip_ops(tr, ctx["chips"])
     if not any(chips):
         return None
-    pieces = self_time(on_line(tr, line))
+    pieces = trace_reduce.self_time(trace_reduce.on_line(tr, line))
     idle = attributed = 0.0
     for iv in chips:
         gaps = trace_reduce.gaps(iv, *win)
         idle += sum(e - s for s, e in gaps)
-        held = overlap_by_name(pieces, gaps)
-        held.pop(PHASE, None)
+        held = trace_reduce.overlap_by_name(pieces, gaps)
+        held.pop(trace_reduce.PHASE, None)
         attributed += sum(held.values())
     return 1.0 - attributed / idle if idle > 0 else None
 
@@ -281,7 +189,7 @@ def phase_spans(tr) -> Dict[str, Any]:
     (lo, hi), _ = got
     inside = [(n, s, e) for n, s, e, _ in tr.program_spans if lo <= s < hi]
     phases = [(e - s) / 1e9 for n, s, e in inside
-              if n == PHASE and e <= hi]
+              if n == trace_reduce.PHASE and e <= hi]
     return {"phase_s": phases,
             "spans_per_phase": len(inside) / max(1, len(phases)),
             "spans_by_name": dict(Counter(n for n, _, _ in inside))}
@@ -339,28 +247,19 @@ def gc_idle_share(ctx) -> Optional[float]:
 # ------------------------------------------------------------------- run
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         log=print) -> Dict[str, Any]:
-    """One run of ``cell`` through ``harness.run_cell``, keeping the
-    program's spans in the trace and the kernels' ``h2d_bytes`` over the
-    window. Returns ``run_cell``'s result with ``ctx['h2d_bytes']`` set,
-    ``phase_wall_s``: the wall seconds of each ``FleetRun.step`` in the
-    window, and ``gc_pause_s``: the garbage-collection pauses in it."""
+    """One run of ``cell`` through ``harness.run_cell``. Returns its result
+    with ``phase_wall_s``: the wall seconds of each ``FleetRun.step`` in
+    the window, and ``gc_pause_s``: the garbage-collection pauses in it."""
     import harness
     from repro.core.fleet import FleetRun
 
     walls: List[float] = []
     mark: Dict[str, Any] = {}
 
-    class ProgramProfiler(harness.Profiler):
-        def stop(self) -> Trace:
-            return load(self.session.stop_and_get_profile_data())
-
-    class CountingRecorder(harness.Recorder):
+    class MarkingRecorder(harness.Recorder):
         """``harness.run_cell`` sets ``in_window`` at the window's start and
-        clears it at its close: the counter is read at both."""
-
-        def __init__(self, session, *args, **kwargs):
-            self._kernels = session.kernels
-            super().__init__(session, *args, **kwargs)
+        clears it at its close: the phase walls and pauses are cut at
+        both."""
 
         @property
         def in_window(self):
@@ -369,15 +268,9 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         @in_window.setter
         def in_window(self, value):
             self._in_window = value
-            counts = [getattr(k, "h2d_bytes", None)
-                      for k in getattr(self, "_kernels", ())]
-            total = None if None in counts or not counts else sum(counts)
             if value:
-                mark.update(h2d=total, steps=len(walls),
-                            t0=time.perf_counter())
+                mark.update(steps=len(walls), t0=time.perf_counter())
             elif "t0" in mark:
-                mark["h2d_bytes"] = (None if total is None
-                                     else total - mark["h2d"])
                 mark["walls"] = walls[mark["steps"]:]
                 mark["gc"] = [d for t, d in pauses if t >= mark["t0"]]
 
@@ -390,17 +283,16 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         finally:
             walls.append(time.perf_counter() - t0)
 
-    saved = harness.Profiler, harness.Recorder
-    harness.Profiler, harness.Recorder = ProgramProfiler, CountingRecorder
+    saved = harness.Recorder
+    harness.Recorder = MarkingRecorder
     FleetRun.step = timed_step
     try:
         with gc_spans() as pauses:
             out = harness.run_cell(cell, seed, seconds, trace, t_start,
                                    log=log)
     finally:
-        harness.Profiler, harness.Recorder = saved
+        harness.Recorder = saved
         FleetRun.step = step
-    out["ctx"]["h2d_bytes"] = mark.get("h2d_bytes")
     out["phase_wall_s"] = mark.get("walls", [])
     out["gc_pause_s"] = mark.get("gc", [])
     return out
@@ -429,8 +321,8 @@ def main(argv=None) -> None:
     ctx = out["ctx"]
     result = {"workload": args.workload, "seed": args.seed,
               "trace": args.trace, "correct": bool(out["correct"]),
-              "metrics": {**harness.metrics_of(cell, ctx, bool(args.trace)),
-                          **read_all(ctx)},
+              "metrics": harness.metrics_of(cell, ctx, bool(args.trace)),
+              "program_metrics": read_all(ctx),
               "h2d_bytes": ctx["h2d_bytes"], "camera_s": ctx["camera_s"],
               "phases": ctx["phases"], "phase_wall_s": out["phase_wall_s"],
               "gc_pause_s": {"count": len(out["gc_pause_s"]),

@@ -1,7 +1,8 @@
-"""The program's spans and host-to-device counter as ``program_spans.py``
-reads them, on the CPU: the loader, the self-time rule that names an idle
-gap, the five metric readers on a hand-built trace with program spans on
-two lines, and a traced run of the reduced cell."""
+"""The program's spans and host-to-device counter as the benchmark reads
+them, on the CPU: the loader and the self-time rule that names an idle gap
+(``trace_reduce``), the five metric readers of ``program_spans.py`` on a
+hand-built trace with program spans on two lines, and a traced run of the
+reduced cell, read by ``run.py``'s readers and by ``program_spans``."""
 import threading
 import time
 
@@ -37,38 +38,38 @@ def hand_built(lanes=1):
              + [(n, s * MS, e * MS, WORKER) for n, s, e in worker])
     ops = {"/device:TPU:0": [(0, 10 * MS), (40 * MS, 50 * MS),
                              (90 * MS, 95 * MS)]}
-    tr = program_spans.Trace(ops, {}, bench, spans)
+    tr = trace_reduce.Trace(ops, {}, bench, spans)
     return {"trace": tr, "chips": 1, "lanes": lanes}
 
 
 def test_trace_keeps_the_three_argument_constructor():
-    tr = program_spans.Trace({}, {}, [("bench.window", 0, 5)])
+    tr = trace_reduce.Trace({}, {}, [("bench.window", 0, 5)])
     assert tr.program_spans == [] and trace_reduce.window(tr) == (0, 5)
-    assert isinstance(tr, trace_reduce.Trace)
+    assert trace_reduce.engine_line(tr) is None
 
 
 def test_self_time_gives_each_instant_to_the_innermost_span():
     spans = [("a", 0, 100), ("b", 10, 40), ("c", 12, 38), ("d", 40, 60),
              ("e", 200, 210)]
-    assert program_spans.self_time(spans) == [
+    assert trace_reduce.self_time(spans) == [
         (0, 10, "a"), (10, 12, "b"), (12, 38, "c"), (38, 40, "b"),
         (40, 60, "d"), (60, 100, "a"), (200, 210, "e")]
-    pieces = program_spans.self_time(spans)
-    assert program_spans.overlap_by_name(pieces, [(5, 15), (35, 45)]) == {
+    pieces = trace_reduce.self_time(spans)
+    assert trace_reduce.overlap_by_name(pieces, [(5, 15), (35, 45)]) == {
         "a": 5, "b": 4, "c": 6, "d": 5}
 
 
 def test_idle_gap_is_named_by_self_time_of_program_spans():
     tr = hand_built()["trace"]
     # wait holds 26 of the 30 ms; frames only 4.
-    assert program_spans.host_activity((10 * MS, 40 * MS),
-                                       tr) == "dacapo.data.wait"
+    assert trace_reduce.host_activity((10 * MS, 40 * MS),
+                                      tr) == "dacapo.data.wait"
     # barrier 12, collect 10, the score flush 18.
-    assert program_spans.host_activity((50 * MS, 90 * MS),
-                                       tr) == "dacapo.issue.score"
+    assert trace_reduce.host_activity((50 * MS, 90 * MS),
+                                      tr) == "dacapo.issue.score"
     # Only dacapo.phase covers it: the benchmark's rule, unchanged.
-    assert program_spans.host_activity((95 * MS, 100 * MS), tr) == "phase"
-    assert program_spans.host_activity(
+    assert trace_reduce.host_activity((95 * MS, 100 * MS), tr) == "phase"
+    assert trace_reduce.host_activity(
         (95 * MS, 100 * MS), trace_reduce.Trace({}, {}, [])) == "other"
 
 
@@ -76,9 +77,9 @@ def test_gap_rule_without_program_spans_is_the_benchmarks_rule():
     spans = [("bench.window", 0, 100), ("bench.phase", 0, 100),
              ("bench.frames", 40, 60), ("bench.fit", 58, 70)]
     for gap in ((42, 58), (60, 70), (80, 90)):
-        assert (program_spans.host_activity(
+        assert (trace_reduce.host_activity(
             gap, trace_reduce.Trace({}, {}, spans))
-            == trace_reduce.host_activity(gap, spans))
+            == trace_reduce.bench_activity(gap, spans))
 
 
 def test_idle_gaps_are_the_longest_named_by_the_rule():
@@ -143,14 +144,14 @@ def test_load_keeps_benchmark_and_program_spans_with_their_lines():
                     th.start()
                     th.join()
     finally:
-        tr = program_spans.load(prof.session.stop_and_get_profile_data())
+        tr = prof.stop()
     assert [n for n, _, _ in tr.spans] == ["bench.window"]
     names = {n: ln for n, _, _, ln in tr.program_spans}
     assert set(names) == {"dacapo.phase", "dacapo.plan",
                           "dacapo.data.synthesize"}
     assert names["dacapo.phase"] == names["dacapo.plan"]
     assert names["dacapo.data.synthesize"] != names["dacapo.phase"]
-    assert program_spans.engine_line(tr) == names["dacapo.phase"]
+    assert trace_reduce.engine_line(tr) == names["dacapo.phase"]
 
 
 def test_gc_pauses_are_timed_and_traced():
@@ -163,7 +164,7 @@ def test_gc_pauses_are_timed_and_traced():
             with program_spans.gc_spans() as pauses:
                 gc.collect()
     finally:
-        tr = program_spans.load(prof.session.stop_and_get_profile_data())
+        tr = prof.stop()
     assert len(pauses) >= 1 and all(d >= 0 for _, d in pauses)
     assert program_spans.GC_SPAN in {n for n, _, _ in tr.spans}
     assert gc.callbacks == callbacks
@@ -180,7 +181,8 @@ def test_gc_idle_share_is_the_idle_time_under_pauses():
 def test_traced_run_reads_the_program_metrics_and_h2d_bytes():
     """The reduced cell, traced on the CPU: no device plane here, so the
     device's metric reads nothing; the counter equals the frames handed to
-    the kernels in the window (served, labeled, SGD) and the SGD labels."""
+    the kernels in the window (served, labeled, SGD) and the SGD labels;
+    ``run.py``'s readers give the same five numbers."""
     cell = reduced_cell()
     out = program_spans.run(cell, SEED, 0.5, True, time.perf_counter(),
                             log=lambda s: None)
@@ -201,3 +203,7 @@ def test_traced_run_reads_the_program_metrics_and_h2d_bytes():
     assert all(d >= 0 for d in out["gc_pause_s"])
     phases = program_spans.phase_spans(ctx["trace"])
     assert phases["phase_s"] and phases["spans_per_phase"] > 10
+    # The readers' arithmetic, as if the counts had come from a chip.
+    read = harness.metrics_of(cell, dict(ctx, device_kind="TPU v5 lite"),
+                              trace=True)
+    assert {k: read[k] for k in got} == got

@@ -52,10 +52,11 @@ def test_program_times_sum_runs_that_start_in_the_window():
 def test_idle_gap_goes_to_the_innermost_span_covering_it():
     spans = [("bench.window", 0, 100), ("bench.phase", 0, 100),
              ("bench.frames", 40, 60), ("bench.fit", 58, 70)]
-    assert trace_reduce.host_activity((42, 58), spans) == "frames"
-    assert trace_reduce.host_activity((60, 70), spans) == "fit"
-    assert trace_reduce.host_activity((80, 90), spans) == "phase"
-    assert trace_reduce.host_activity((80, 90), spans[:1]) == "other"
+    tr = trace_reduce.Trace({}, {}, spans)  # no program spans in it
+    assert trace_reduce.host_activity((42, 58), tr) == "frames"
+    assert trace_reduce.host_activity((60, 70), tr) == "fit"
+    assert trace_reduce.host_activity((80, 90), tr) == "phase"
+    assert trace_reduce.bench_activity((80, 90), spans[:1]) == "other"
 
 
 def test_trace_window_is_the_longest_window_span():
@@ -196,3 +197,41 @@ def test_reference_sgd_step_matches_the_program(kind):
                     jax.tree_util.tree_leaves(got[:3])):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
                                    atol=2e-6)
+
+
+def test_training_gaps_compare_vectors_not_norms():
+    """A gradient turned at the same per-leaf norms (as half a batch turns
+    it) reads about 0 by norms and fails by vectors, under every
+    configuration's limits."""
+    import check
+
+    rng = np.random.default_rng(7)
+    shapes = {"a": (64, 32), "b": (32,), "c": (8, 8, 4)}
+    grad = {k: rng.normal(size=s).astype(np.float32)
+            for k, s in shapes.items()}
+    turned = {k: np.roll(g, 1) for k, g in grad.items()}  # same norms
+    p0 = {k: rng.normal(size=s).astype(np.float32) for k, s in shapes.items()}
+    lr = 1e-3
+    truth = {"loss": [2.0, 1.9, 1.8], "grad1": grad,
+             "params3": {k: p0[k] - 3 * lr * g for k, g in grad.items()}}
+    got = {"loss": [2.0, 1.9, 1.8], "mom1": turned,
+           "params3": {k: p0[k] - 3 * lr * g for k, g in turned.items()}}
+
+    def by_norms(a, b):
+        return max(abs(np.linalg.norm(a[k]) - np.linalg.norm(b[k]))
+                   / np.linalg.norm(b[k]) for k in shapes)
+
+    assert by_norms(turned, grad) < 1e-6
+    gaps = check.training_gaps(truth, p0, got)
+    assert gaps["sgd_loss"] == 0
+    assert gaps["sgd_grad"] > 1 and gaps["sgd_update"] > 1
+    for name in ("vitb32-vitb16", "resnet18-wrn50"):
+        limits = harness.load_json(harness.BENCH_DIR / "configs"
+                                   / f"{name}.json")["limits"]
+        answers = {"label_gap": 0.0, "serve_gap": 0.0}
+        assert not check.correct({**gaps, **answers}, limits)
+        assert check.correct({**gaps, **answers, "sgd_grad": 0.0,
+                              "sgd_update": 0.0}, limits)
+    same = check.training_gaps(truth, p0, {**got, "mom1": grad,
+                                           "params3": truth["params3"]})
+    assert same["sgd_grad"] == 0 and same["sgd_update"] == 0
